@@ -136,7 +136,7 @@ def run():
     rows.append(["expand(pallas)" + ("[interp]" if interp else ""),
                  "4x4x16x64", t_k * 1e3,
                  flops / PEAK_FLOPS * 1e6, byts / HBM_BW * 1e6])
-    # the int8 Pallas expand kernel (MXU int8 matmul + accumulator dequant),
+    # the int8 Pallas expand kernel (packed code-row DMA + VMEM dequant),
     # same interpret-mode caveat
     qc_small = quantize_corpus(pts)
     t_k8 = _wall(lambda: expand_frontier(qc_small, nbrs, fr, qs,

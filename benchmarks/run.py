@@ -1188,6 +1188,8 @@ def main(argv=None):
     p.add_argument("--min-ap", type=float, default=0.6)
     args = p.parse_args(argv)
     quick = not args.full
+    from repro.utils import enable_compile_cache
+    enable_compile_cache()
 
     if args.smoke:
         return smoke(min(args.n, 4_000), args.min_qps, args.min_ap)

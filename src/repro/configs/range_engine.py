@@ -38,10 +38,9 @@ class EngineDeployConfig:
     corpus_dtype: str = "float32"
     range_cfg: RangeConfig = dataclasses.field(default_factory=lambda: RangeConfig(
         search=SearchConfig(beam=64, max_beam=64, visit_cap=256,
-                            # multi-node frontier expansion; the TPU deploy
-                            # additionally flips use_expand_kernel=True (left
-                            # False here so the dry-run lowers on host
-                            # devices, where Pallas TPU calls don't exist)
+                            # multi-node frontier expansion (the XLA path;
+                            # use_expand_kernel stays off: which path a
+                            # deployment should run is not measured yet)
                             expand_width=4),
         mode="greedy", result_cap=1024, frontier_rounds=2048))
 

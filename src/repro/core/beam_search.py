@@ -13,7 +13,7 @@ This implements the paper's Algorithms 1 (BeamSearch), 3/4 (EarlyStopping) and
   ``kernels.expand``). This cuts the iteration count ~``expand_width``-fold,
   which is what makes the traversal accelerator-friendly: per-iteration
   fixed costs (sort, control flow, the vmapped-batch straggler effect)
-  amortize over E expansions, and the E*R distance tile is one MXU matmul
+  amortize over E expansions, and the E*R distance tile is one pass
   instead of E skinny ones.
 * **Bitset visited filtering**: every node is marked in a packed per-query
   ``(W,) uint32`` bitset (``core.bitset``) when it first *enters the beam*
@@ -57,7 +57,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ..kernels.expand import expand_frontier, expand_frontier_1
+from ..kernels.expand import expand_frontier, expand_frontier_1, pack_int8_rows
 from ..utils import INVALID_ID
 from .bitset import (
     DEFAULT_BITSET_CAP_BITS,
@@ -97,7 +97,7 @@ class SearchConfig:
     # kept as the correctness/perf baseline — see _step_reference).
     expand_width: int = 4
     bitset_cap_bits: int = DEFAULT_BITSET_CAP_BITS  # seen-filter memory bound
-    use_expand_kernel: bool = False  # Pallas expand kernel (real TPU only)
+    use_expand_kernel: bool = False  # Pallas expand kernel (compiles for TPU only)
     # declared corpus storage dtype: "float32" | "bfloat16" | "int8". The
     # search itself dispatches on the corpus *value* (array vs
     # QuantizedCorpus); this knob is what deploy configs / builders consult
@@ -269,31 +269,27 @@ def in_range_count(st: BeamState, r, width: Optional[jnp.ndarray] = None) -> jnp
 
 
 def _expand_tile(points, graph: Graph, frontier, q, cfg: SearchConfig,
-                 point_norms=None):
+                 packed=None):
     """Fused expansion of an (E,) frontier: (E*R,) ids/dists + n_dist.
 
-    The Pallas kernel path is opt-in (real TPU; it computes norms in-VMEM);
-    the XLA reference is the same fused dataflow and is what CPU CI and dry
-    runs execute.
+    The Pallas kernel path is opt-in and compiles for TPU only; the XLA
+    reference is the same dataflow and is what runs by default.
+    ``packed`` is the kernel's int8 row layout from ``_kernel_operand``.
     """
     if cfg.use_expand_kernel:
         ids, dists, nd = expand_frontier(
             points, graph.neighbors, frontier[None], q[None],
-            metric=cfg.metric, use_pallas=True, interpret=False)
+            metric=cfg.metric, use_pallas=True, packed=packed)
         return ids[0], dists[0], nd[0]
-    return expand_frontier_1(points, graph.neighbors, frontier, q, cfg.metric,
-                             point_norms)
+    return expand_frontier_1(points, graph.neighbors, frontier, q, cfg.metric)
 
 
-def _point_norms(points, cfg: SearchConfig):
-    """Optional |x|^2 precompute for the matmul-form distances.
-
-    Disabled (returns None): on CPU a vmapped (T, d) x (d,) matvec dispatches
-    as a batched GEMM each iteration and measured *slower* than the fused
-    diff-form elementwise pass; the Pallas kernel computes norms in VMEM
-    itself, so nothing needs them. Kept as the single switch point should a
-    future XLA backend prefer the norm form.
-    """
+def _kernel_operand(points, cfg: SearchConfig):
+    """Per-dispatch operand of the expand kernel, built before the search
+    loop so it is not rebuilt per iteration: the int8 codes repacked for
+    row DMA (``kernels.expand.pack_int8_rows``), or None."""
+    if cfg.use_expand_kernel and getattr(points, "codes", None) is not None:
+        return pack_int8_rows(points.codes)
     return None
 
 
@@ -387,7 +383,7 @@ def _step_reference(points, graph: Graph, q, r, es_radius, cfg: SearchConfig,
 
 
 def _step(points, graph: Graph, q, r, es_radius, cfg: SearchConfig, st: BeamState,
-          point_norms=None) -> BeamState:
+          packed=None) -> BeamState:
     if cfg.eff_expand_width == 1:
         return _step_reference(points, graph, q, r, es_radius, cfg, st)
     L = cfg.max_beam
@@ -439,7 +435,7 @@ def _step(points, graph: Graph, q, r, es_radius, cfg: SearchConfig, st: BeamStat
 
     # -- fused expansion + bitset seen filter --------------------------------
     nbr_ids, nd, nd_inc = _expand_tile(points, graph, cand_ids, q, cfg,
-                                       point_norms)
+                                       packed)
     valid = nbr_ids != INVALID_ID
     seen = bitset_contains(st.visited_bits, jnp.where(valid, nbr_ids, 0)) & valid
     fresh = valid & ~seen
@@ -532,11 +528,11 @@ def beam_search(
     the batch entry point below carries them per-lane)."""
     esr = jnp.asarray(jnp.inf, jnp.float32) if es_radius is None else jnp.asarray(es_radius, jnp.float32)
     r = jnp.asarray(r, jnp.float32)
-    pnorms = _point_norms(points, cfg)
+    packed = _kernel_operand(points, cfg)
     st = init_state(points, q, start_ids, cfg)
     st = jax.lax.while_loop(
         lambda s: ~s.done,
-        lambda s: _step(points, graph, q, r, esr, cfg, s, pnorms),
+        lambda s: _step(points, graph, q, r, esr, cfg, s, packed),
         st,
     )
     return st

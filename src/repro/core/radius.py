@@ -71,8 +71,21 @@ def sweep(
                          zero_frac=zero_frac, robustness=slope, counts=counts)
 
 
+# The grid's low edge never starts above the radius at which a query
+# expects this many points in range.
+GRID_LO_MATCHES = 50
+
+
 def default_grid(points, queries, metric: str = "l2", num: int = 48) -> np.ndarray:
-    """A grid spanning ~0% to ~100% capture, from a distance sample."""
+    """A grid spanning ~0% to ~100% capture, from a distance sample.
+
+    The low edge is the 0.0005 quantile of the sampled query-to-point
+    distances, or the ``GRID_LO_MATCHES / n`` quantile where that is lower.
+    Up to n = 100,000 the two are the same. Above it the bare quantile
+    would start the grid at more expected matches per query the larger the
+    corpus (about 500 at 1M rows), above the zero-heavy regime
+    ``select_radius`` aims for, and the selection would fall onto the
+    capture plateau where whole clusters are in range."""
     pts = np.asarray(points)
     qs = np.asarray(queries)
     sample = pts[np.random.default_rng(0).choice(pts.shape[0], size=min(2048, pts.shape[0]), replace=False)]
@@ -80,7 +93,8 @@ def default_grid(points, queries, metric: str = "l2", num: int = 48) -> np.ndarr
         d = ((qs[:, None, :] - sample[None, : min(512, sample.shape[0]), :]) ** 2).sum(-1)
     else:
         d = -(qs @ sample[: min(512, sample.shape[0])].T)
-    lo, hi = np.quantile(d, 0.0005), np.quantile(d, 0.9995)
+    q_lo = min(0.0005, GRID_LO_MATCHES / pts.shape[0])
+    lo, hi = np.quantile(d, q_lo), np.quantile(d, 0.9995)
     if metric == "l2":
         lo = max(lo, 1e-9)
         return np.geomspace(lo, hi, num).astype(np.float32)

@@ -31,7 +31,7 @@ from .beam_search import (
     _expand_tile,
     _f32_ascending_key,
     _f32_from_key,
-    _point_norms,
+    _kernel_operand,
     beam_search_batch,
     broadcast_radius,
     in_range_count,
@@ -244,7 +244,7 @@ def _unpack_greedy(ps: _PackedGreedyState) -> GreedyState:
 
 
 def _greedy_step(points, graph: Graph, q, r, cap: int, scfg: SearchConfig,
-                 gs: _PackedGreedyState, point_norms=None) -> _PackedGreedyState:
+                 gs: _PackedGreedyState, packed=None) -> _PackedGreedyState:
     """Expand the next E result-buffer entries through the fused expand path
     (same kernel as phase 1), appending fresh in-range neighbors.
 
@@ -258,7 +258,7 @@ def _greedy_step(points, graph: Graph, q, r, cap: int, scfg: SearchConfig,
     nodes = jnp.where(lane_ok, jnp.take(gs.res[:, 0], ridx), INVALID_ID)
 
     nbr_ids, nd, nd_inc = _expand_tile(points, graph, nodes, q, scfg,
-                                       point_norms)
+                                       packed)
     valid = nbr_ids != INVALID_ID
     seen = bitset_contains(gs.seen_bits, jnp.where(valid, nbr_ids, 0)) & valid
     new = valid & ~seen & (nd <= r)
@@ -308,10 +308,10 @@ def _greedy_run(points, graph: Graph, q, r, gs: GreedyState, cap: int,
             lambda g: _greedy_step_reference(points, graph, q, r, cap, scfg, g,
                                              exact_bits),
             gs)
-    pnorms = _point_norms(points, scfg)
+    packed = _kernel_operand(points, scfg)
     ps = jax.lax.while_loop(
         cond,
-        lambda g: _greedy_step(points, graph, q, r, cap, scfg, g, pnorms),
+        lambda g: _greedy_step(points, graph, q, r, cap, scfg, g, packed),
         _pack_greedy(gs))
     return _unpack_greedy(ps)
 

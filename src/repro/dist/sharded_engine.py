@@ -21,13 +21,15 @@ and the union needs no dedup — only the merge sort.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.beam_search import broadcast_radius
 from ..core.corpus import corpus_cast, pad_corpus_rows
@@ -35,7 +37,7 @@ from ..core.graph import Graph
 from ..core.labels import LabelFilter
 from ..core.range_search import RangeConfig, RangeResult, range_search_fused
 from ..utils import INVALID_ID, cdiv
-from .compat import shard_map
+from jax import shard_map
 from .sharding import _axis_size
 
 
@@ -101,6 +103,7 @@ def build_sharded(
     labels=None,
     tier: bool = False,
     resident_mb: float = None,
+    mesh: Optional[Mesh] = None,
 ) -> ShardedCorpus:
     """Partition ``points`` into ``n_shards`` contiguous blocks and build one
     sub-index per block with ``build_fn``. A short last block is padded to
@@ -109,10 +112,8 @@ def build_sharded(
     and the stacked arrays stay rectangular.
 
     ``lane_pad > 0`` pads every sub-index's degree axis to that multiple
-    (``Graph.lane_padded``) so the stacked adjacency is ready for the fused
-    Pallas expand kernel (``SearchConfig.use_expand_kernel``), whose VMEM
-    blocks want R on a 128-lane boundary — done once here rather than per
-    search dispatch.
+    (``Graph.lane_padded``), done once here rather than per search
+    dispatch.
 
     ``corpus_dtype`` controls per-shard storage: graphs always build on the
     exact f32 block; "int8" then quantizes each shard *locally* (per-shard
@@ -129,7 +130,14 @@ def build_sharded(
     the cast block for float dtypes), while each shard's raw f32 rerank
     rows move into its own host row store (``ShardedCorpus.tiers``).
     ``resident_mb`` caps each shard's device row cache. Tiered sharded
-    corpora are served by the host fan-out path only."""
+    corpora are served by the host fan-out path only.
+
+    ``mesh`` places each shard on the device(s) of its slot along the
+    ``"model"`` axis: the shard is built there (the builds of different
+    devices run at once, one thread each) and the stacked
+    leaves are sharded ``P("model", ...)`` over the mesh, so no device
+    holds another device's shard. Without it every shard is built and
+    stacked on the default device."""
     pts = np.asarray(points)
     n_total, d = pts.shape
     n = cdiv(n_total, n_shards)
@@ -138,53 +146,107 @@ def build_sharded(
         if labels.shape[0] != n_total:
             raise ValueError(
                 f"labels rows ({labels.shape[0]}) != corpus size ({n_total})")
+    homes = [None] * n_shards
+    if mesh is not None:
+        if n_shards % mesh.shape["model"]:
+            raise ValueError(f"{n_shards} shards do not lay out on model "
+                             f"axis of size {mesh.shape['model']}")
+        cols = np.moveaxis(mesh.devices, mesh.axis_names.index("model"), 0)
+        per = n_shards // mesh.shape["model"]
+        homes = [cols[s // per].flat[0] for s in range(n_shards)]
+
+    def on(s):
+        return (contextlib.nullcontext() if homes[s] is None
+                else jax.default_device(homes[s]))
+
+    def build(s):
+        with on(s):
+            return build_fn(jnp.asarray(pts[s * n:(s + 1) * n]))
+
+    if mesh is None:
+        built = [build(s) for s in range(n_shards)]
+    else:  # a thread per shard: a device whose dispatch queue is full
+        # must not hold back the builds of the others
+        with ThreadPoolExecutor(n_shards) as pool:
+            built = list(pool.map(build, range(n_shards)))
     blocks, nbrs, starts, labs, tiers = [], [], [], [], []
     for s in range(n_shards):
-        block = pts[s * n:(s + 1) * n]
-        graph, start_ids = build_fn(jnp.asarray(block))
-        if lane_pad:
-            graph = graph.lane_padded(lane_pad)
-        neighbors = np.asarray(graph.neighbors)
-        n_pad = n - block.shape[0]
-        stored = corpus_cast(jnp.asarray(block), corpus_dtype)
-        if n_pad:  # pad points AND adjacency (INVALID = no edge)
-            if corpus_dtype == "int8":
-                stored = pad_corpus_rows(stored, n_pad, _FAR)
-            else:
-                stored = jnp.concatenate(
-                    [stored,
-                     jnp.full((n_pad, d), _FAR, dtype=stored.dtype)], axis=0)
-            neighbors = np.concatenate(
-                [neighbors,
-                 np.full((n_pad, neighbors.shape[1]), INVALID_ID, np.int32)],
-                axis=0)
-        if tier:
-            # split the (padded) shard: raw rows -> this shard's host store,
-            # device arm -> the stacked points. The tier keeps device=None —
-            # the stacked arm is sliced back in per search (with_device).
-            from ..tier import tiered_corpus
-            t = tiered_corpus(stored, corpus_dtype=corpus_dtype,
-                              resident_mb=resident_mb)
-            tiers.append(t.with_device(None))
-            stored = t.device
-        blocks.append(stored)
-        nbrs.append(jnp.asarray(neighbors))
-        starts.append(jnp.asarray(start_ids, jnp.int32).reshape(-1))
-        if labels is not None:
-            lab = labels[s * n:(s + 1) * n]
-            if n_pad:
-                lab = np.concatenate(
-                    [lab, np.zeros((n_pad, lab.shape[1]), np.uint32)], axis=0)
-            labs.append(jnp.asarray(lab))
+        with on(s):  # every array of shard s lives on its home device
+            block = pts[s * n:(s + 1) * n]
+            graph, start_ids = built[s]
+            if lane_pad:
+                graph = graph.lane_padded(lane_pad)
+            neighbors = np.asarray(graph.neighbors)
+            n_pad = n - block.shape[0]
+            stored = corpus_cast(jnp.asarray(block), corpus_dtype)
+            if n_pad:  # pad points AND adjacency (INVALID = no edge)
+                if corpus_dtype == "int8":
+                    stored = pad_corpus_rows(stored, n_pad, _FAR)
+                else:
+                    stored = jnp.concatenate(
+                        [stored,
+                         jnp.full((n_pad, d), _FAR, dtype=stored.dtype)], axis=0)
+                neighbors = np.concatenate(
+                    [neighbors,
+                     np.full((n_pad, neighbors.shape[1]), INVALID_ID, np.int32)],
+                    axis=0)
+            if tier:
+                # split the (padded) shard: raw rows -> this shard's host store,
+                # device arm -> the stacked points. The tier keeps device=None —
+                # the stacked arm is sliced back in per search (with_device).
+                from ..tier import tiered_corpus
+                t = tiered_corpus(stored, corpus_dtype=corpus_dtype,
+                                  resident_mb=resident_mb)
+                tiers.append(t.with_device(None))
+                stored = t.device
+            blocks.append(stored)
+            nbrs.append(jnp.asarray(neighbors))
+            starts.append(jnp.asarray(start_ids, jnp.int32).reshape(-1))
+            if labels is not None:
+                lab = labels[s * n:(s + 1) * n]
+                if n_pad:
+                    lab = np.concatenate(
+                        [lab, np.zeros((n_pad, lab.shape[1]), np.uint32)], axis=0)
+                labs.append(jnp.asarray(lab))
+    if mesh is None:
+        stack = lambda *xs: jnp.stack(xs)
+    else:
+        stack = lambda *xs: _stack_placed(mesh, xs)
     return ShardedCorpus(
-        points=jax.tree.map(lambda *xs: jnp.stack(xs), *blocks),
-        neighbors=jnp.stack(nbrs),
-        start_ids=jnp.stack(starts),
+        points=jax.tree.map(stack, *blocks),
+        neighbors=stack(*nbrs),
+        start_ids=stack(*starts),
         offsets=jnp.arange(n_shards, dtype=jnp.int32) * n,
         n_total=n_total,
-        labels=None if labels is None else jnp.stack(labs),
+        labels=None if labels is None else stack(*labs),
         tiers=tuple(tiers) if tier else None,
     )
+
+
+def _stack_placed(mesh: Mesh, shards) -> jax.Array:
+    """Stack per-shard arrays into one array sharded ``P("model")`` on its
+    leading axis, assembled from pieces on their own devices."""
+    sharding = NamedSharding(mesh, P("model", *([None] * shards[0].ndim)))
+    shape = (len(shards),) + shards[0].shape
+    pieces = []
+    for dev, idx in sharding.addressable_devices_indices_map(shape).items():
+        rows = range(len(shards))[idx[0]]
+        pieces.append(jax.device_put(
+            jnp.stack([shards[i] for i in rows]) if len(rows) > 1
+            else shards[rows[0]][None], dev))
+    return jax.make_array_from_single_device_arrays(shape, sharding, pieces)
+
+
+def shard_view(x, s: int):
+    """Shard ``s`` of a stacked leaf. Where the leaf is laid out one shard
+    per device (``build_sharded(mesh=)``), this is that device's own piece,
+    with no transfer; otherwise ``x[s]``."""
+    if isinstance(x, jax.Array) and len(x.sharding.device_set) > 1:
+        for piece in x.addressable_shards:
+            rows = range(x.shape[0])[piece.index[0]]
+            if len(rows) == 1 and rows[0] == s:
+                return piece.data[0]
+    return x[s]
 
 
 def _remap_global(ids, offset, n_total: int):

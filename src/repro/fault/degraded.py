@@ -38,7 +38,9 @@ from ..core.beam_search import broadcast_radius
 from ..core.graph import Graph
 from ..core.labels import LabelFilter
 from ..core.range_search import RangeConfig, RangeResult, range_search_fused
-from ..dist.sharded_engine import ShardedCorpus, _remap_global, union_merge
+from ..dist.sharded_engine import (
+    ShardedCorpus, _remap_global, shard_view, union_merge,
+)
 from ..tier import TierFetchError
 from ..utils import INVALID_ID
 from .errors import SHARD_LOST
@@ -174,22 +176,25 @@ def _search_one_shard(corpus: ShardedCorpus, s: int, queries, radii, cfg,
     A tiered corpus composes shard ``s``'s host store back onto its slice
     of the stacked device arm, so the per-shard rerank fetches that
     shard's raw rows (shard-local slot space) before the global remap."""
-    shard_pts = jax.tree.map(lambda x: x[s], corpus.points)
+    shard_pts = jax.tree.map(lambda x: shard_view(x, s), corpus.points)
     tiers = getattr(corpus, "tiers", None)
     if tiers is not None:
         shard_pts = tiers[s].with_device(shard_pts)
     res = range_search_fused(
-        corpus=shard_pts, graph=Graph(neighbors=corpus.neighbors[s]),
-        queries=queries, start_ids=corpus.start_ids[s], r=radii, cfg=cfg,
+        corpus=shard_pts, graph=Graph(neighbors=shard_view(corpus.neighbors, s)),
+        queries=queries, start_ids=shard_view(corpus.start_ids, s), r=radii,
+        cfg=cfg,
         es_radius=es_vec,
         tombstones=None if tombstones is None else tombstones[s],
         labels=None if label_filter is None else corpus.labels[s],
         label_filter=label_filter)
     gids = _remap_global(res.ids, corpus.offsets[s], corpus.n_total)
-    return dataclasses.replace(
+    res = dataclasses.replace(
         res, ids=gids,
         dists=jnp.where(gids == INVALID_ID, jnp.inf, res.dists),
         count=jnp.sum(gids != INVALID_ID, axis=1).astype(jnp.int32))
+    # a shard placed on its own device answers there; merge on the default
+    return jax.device_put(res, jax.devices()[0])
 
 
 def merge_shard_results(per_shard: List[Optional[RangeResult]],

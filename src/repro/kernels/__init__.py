@@ -1,23 +1,20 @@
-"""Pallas TPU kernels for the framework's compute hot spots.
+"""Pallas TPU kernels, each with ``kernel.py`` (pallas_call + BlockSpec),
+``ops.py`` (jit wrapper with an XLA path) and ``ref.py`` (pure-jnp oracle):
 
-Four kernels (DESIGN.md §7), each with ``kernel.py`` (pallas_call +
-BlockSpec), ``ops.py`` (jit wrapper with an XLA fallback), ``ref.py``
-(pure-jnp oracle):
-
+* ``expand``     — fused frontier expansion: neighbor-row DMA gather + the
+  distance reduction, the search loop's hot path (opt-in through
+  ``SearchConfig.use_expand_kernel``). Compiles for TPU v5e (f32 and int8).
 * ``rangescan``  — tiled exact range scan (fused MXU distance + in-range
-  count + bounded top-K collect). Ground truth, brute force,
-  ``retrieval_cand``.
-* ``gatherdist`` — scalar-prefetch row gather + fused distance (beam
-  expansion's irregular memory pattern).
-* ``expand``     — fused multi-node frontier expansion: adjacency gather +
-  neighbor-vector DMA gather + MXU distances + one-pass tile dedup (the
-  search loop's per-iteration hot path).
-* ``flashattn``  — flash attention fwd with GQA, sliding window, soft-cap
-  (LM serving).
+  count + bounded top-K collect).
+* ``gatherdist`` — scalar-prefetch row gather + fused distance.
+* ``flashattn``  — flash attention fwd with GQA, sliding window, soft-cap.
 
-CPU tests run ``interpret=True``; dry-run lowering uses the XLA fallback
-(``use_pallas=False``) since Pallas TPU custom calls don't lower on the CPU
-host platform.
+Only ``expand`` compiles for the chip and sits on a served path. The TPU
+compiler refuses ``gatherdist`` (its ``(1, d)`` row blocks) and
+``rangescan`` (``failed to legalize operation 'scf.for'``), and
+``rerank_fetch`` (``(1, 16)`` output block); they run in interpret mode
+only, and no served path calls them. Every wrapper defaults to
+``interpret=False``: CPU tests ask for ``interpret=True``.
 """
 from .expand import expand_frontier, expand_frontier_ref
 from .flashattn import flash_attention, flash_attention_ref

@@ -1,37 +1,40 @@
-"""Pallas TPU kernel: fused multi-node frontier expansion.
+"""Pallas TPU kernels of the fused frontier expansion: the neighbor-row
+gather and the distance reduction in one pass.
 
-One grid step expands one (query, frontier-node) pair: it pulls the node's
-adjacency row into VMEM via a scalar-prefetch-driven BlockSpec, DMA-gathers
-the R neighbor vectors straight from the corpus in HBM (``pltpu.ANY`` — the
-corpus never materializes as a gathered (E, R, d) tensor in XLA), computes
-all R distances in one MXU matmul against the query, and masks duplicate
-neighbor ids against every earlier row of the same query's E*R tile in the
-same pass. This fuses what the unfused path does as four XLA ops
-(``out_neighbors`` gather + vector gather + distance + three broadcast
-dedups) into a single pipelined kernel.
+For one query and its ``T = E*R`` candidate ids (the adjacency rows of the
+E frontier nodes, flattened), a grid step DMAs the T candidate rows
+straight from the corpus in HBM (``pl.ANY``) into a ``(T, d)`` VMEM
+scratch and reduces them against the query there, so the gathered
+``(Q, T, d)`` tile never exists in HBM. The adjacency gather, validity
+masks and first-occurrence dedup stay in XLA
+(``ref.expand_frontier_1``): they move ``T`` ids, not ``T*d`` values.
 
-Layout:
+Layout rules the TPU compiler (Mosaic) enforces, and how each is met:
 
-* grid ``(Q, E)`` — E innermost, so the steps of one query run back to back
-  and the per-query dedup tile in scratch is valid (the grid must stay
-  sequential; do not mark these dimensions parallel).
-* scalar prefetch: flattened frontier ids (clamped) + validity flags. The
-  adjacency BlockSpec indexes rows directly off the prefetched ids, so the
-  HBM->VMEM row DMA for step i+1 issues while step i computes.
-* the neighbor-vector gather is a manual ``make_async_copy`` loop into a
-  (R, d) VMEM scratch (the paged-attention pattern): BlockSpecs cannot
-  express a data-dependent gather, DMAs can.
-* distances: ``x @ q`` on the MXU (f32 accumulation), plus rank-1 norm
-  corrections for L2. A bf16-stored corpus is gathered in bf16 (halving the
-  dominant HBM term) and cast to f32 only in VMEM.
-* dedup: the kernel keeps the tile's surviving ids in a persistent
-  (E*R,) VMEM scratch; each row masks against all earlier rows plus itself
-  (first occurrence wins), exactly matching ``ref.expand_frontier_ref``.
+* A block's last two dims must divide by (8, 128) or equal the array's.
+  Per-query operands are therefore shaped ``(Q, 1, X)`` with ``(1, 1, X)``
+  blocks; under ``vmap`` (the search loop) the batch dim is prepended and
+  the same rule holds.
+* The candidate ids drive DMA addresses, so they are a blocked SMEM input
+  (not scalar prefetch: ``vmap`` of a ``pallas_call`` whose scalar-prefetch
+  operands are batched falls back to a loop of one-query calls).
+* A DMA'd row slice must cover whole tiles of the HBM layout. An f32 row
+  with ``d % 128 == 0`` does. An int8 row does not (int8 packs four rows
+  per 32-bit word), so the int8 variant reads ``pack_int8_rows``: four
+  corpus rows interleaved bytewise in one int32 row of ``D`` lanes.
+* Distances come out lane-major (one ``(1, T)`` row per query): both
+  kernels reduce the transposed ``(d, T)`` tile over sublanes.
 
-VMEM per step (f32 corpus, defaults E=4, R=64, d=128): adjacency row
-``4R`` B + vector scratch ``R*d*4`` = 32 KiB + dedup tile ``E*R*4`` = 1 KiB
-+ query row ``4d`` + out blocks ``8R`` — well under the 16 MiB budget; the
-vector scratch dominates and scales as ``R*d*itemsize``.
+Both use the diff form ``sum((x - q)^2)`` in f32 with the query in f32, as
+the XLA reference does, so the paths round alike; the int8 kernel
+dequantizes each code row by its scale in VMEM, and the wrapper applies the
+certified lower bound. (Quantizing the query for an int8 MXU contraction
+widens every bound by the query's own error: measured on a TPU v5e at
+100k rows, it cost 0.013 AP against the XLA int8 path.)
+
+Compiled for TPU v5e at N=1M, d=128, R=32 and R=128, E=4, Q=128
+(``tests/test_tpu_compile.py``). A bf16 corpus runs only in interpret mode:
+its rows, like int8 ones, are packed across sublanes.
 """
 from __future__ import annotations
 
@@ -42,278 +45,125 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...dist.compression import GUARD_SLACK
-from ...utils import INVALID_ID
+
+def _gather_rows(ids_ref, src_hbm, dst_vmem, sem):
+    """DMA ``src_hbm[ids[j]]`` into ``dst_vmem[j]`` for every j: start all
+    copies, then wait for all, so the row fetches overlap."""
+    def copy(j):
+        return pltpu.make_async_copy(src_hbm.at[pl.ds(ids_ref[0, 0, j], 1)],
+                                     dst_vmem.at[pl.ds(j, 1)], sem)
+
+    def start(j, c):
+        copy(j).start()
+        return c
+
+    def wait(j, c):
+        copy(j).wait()
+        return c
+
+    jax.lax.fori_loop(0, dst_vmem.shape[0], start, 0)
+    jax.lax.fori_loop(0, dst_vmem.shape[0], wait, 0)
 
 
-def _expand_kernel(
-    fid_ref,    # (Q*E,) int32 scalar-prefetch: clamped frontier ids
-    fval_ref,   # (Q*E,) int32 scalar-prefetch: frontier validity flags
-    adj_ref,    # (1, R) the frontier node's adjacency row
-    pts_ref,    # (N, d) corpus, ANY/HBM — gathered by manual DMA
-    q_ref,      # (1, d) the query row
-    ids_ref,    # (1, R) int32 out: deduped neighbor ids
-    dist_ref,   # (1, R) f32 out: distances (+inf where masked)
-    cnt_ref,    # (1, 1) int32 out: distances computed (pre-dedup)
-    vec_ref,    # (R, d) VMEM scratch: gathered neighbor vectors
-    tile_ref,   # (E*R,) int32 VMEM scratch: per-query surviving-id tile
-    sem,        # DMA semaphore
-    *,
-    n_nodes: int,
-    expand_width: int,
-    metric: str,
-):
-    qi = pl.program_id(0)
-    e = pl.program_id(1)
-    i = qi * expand_width + e
-
-    @pl.when(e == 0)
-    def _reset_tile():
-        tile_ref[...] = jnp.full_like(tile_ref, INVALID_ID)
-
-    adj = adj_ref[0, :]                       # (R,) neighbor ids
-    n_ok = (adj >= 0) & (adj < n_nodes)
-    safe = jnp.where(n_ok, adj, 0)
-
-    def gather(r, _):
-        cp = pltpu.make_async_copy(pts_ref.at[safe[r]], vec_ref.at[r], sem)
-        cp.start()
-        cp.wait()
-        return 0
-
-    jax.lax.fori_loop(0, adj.shape[0], gather, 0)
-
-    x = vec_ref[...].astype(jnp.float32)      # (R, d)
-    q = q_ref[0, :].astype(jnp.float32)       # (d,)
-    dots = jax.lax.dot_general(
-        x, q[:, None], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )[:, 0]                                   # (R,) MXU
+def _dist_kernel(ids_ref, q_ref, pts_hbm, out_ref, vec_ref, sem, *, metric):
+    _gather_rows(ids_ref, pts_hbm, vec_ref, sem)
+    x = vec_ref[...].astype(jnp.float32)      # (T, d)
+    q = q_ref[0].astype(jnp.float32)          # (1, d)
     if metric == "l2":
-        xn = jnp.sum(x * x, axis=1)
-        qn = jnp.sum(q * q)
-        dist = jnp.maximum(xn + qn - 2.0 * dots, 0.0)
+        diff = x - q
+        v = diff * diff
     else:  # ip
-        dist = -dots
-
-    # dedup: earlier rows of this query's tile, then first-in-row wins
-    prev = tile_ref[...]                      # (E*R,)
-    seen_prev = jnp.any(adj[:, None] == prev[None, :], axis=1)
-    rr = jnp.arange(adj.shape[0])
-    dup_row = jnp.any(
-        (adj[:, None] == adj[None, :]) & (rr[None, :] < rr[:, None])
-        & n_ok[:, None] & n_ok[None, :],
-        axis=1,
-    )
-    f_ok = fval_ref[i] > 0
-    keep = n_ok & (~seen_prev) & (~dup_row) & f_ok
-
-    kept = jnp.where(keep, adj, INVALID_ID)
-    ids_ref[0, :] = kept
-    dist_ref[0, :] = jnp.where(keep, dist, jnp.inf)
-    cnt_ref[0, 0] = jnp.sum((n_ok & f_ok).astype(jnp.int32))
-    tile_ref[pl.ds(e * adj.shape[0], adj.shape[0])] = kept
+        v = -(x * q)
+    out_ref[0] = jnp.sum(jnp.transpose(v), axis=0, keepdims=True)   # (1, T)
 
 
-def _expand_kernel_int8(
-    fid_ref,    # (Q*E,) int32 scalar-prefetch: clamped frontier ids
-    fval_ref,   # (Q*E,) int32 scalar-prefetch: frontier validity flags
-    adj_ref,    # (1, R) the frontier node's adjacency row
-    codes_ref,  # (N, d) int8 corpus codes, ANY/HBM — gathered by manual DMA
-    meta_ref,   # (N, 3) f32 [scale, |x_hat|^2, err] per row, ANY/HBM
-    q_ref,      # (1, d) the query row (f32)
-    ids_ref,    # (1, R) int32 out
-    dist_ref,   # (1, R) f32 out
-    cnt_ref,    # (1, 1) int32 out
-    cvec_ref,   # (R, d) int8 VMEM scratch: gathered neighbor codes
-    mvec_ref,   # (R, 3) f32 VMEM scratch: gathered neighbor metadata
-    tile_ref,   # (E*R,) int32 VMEM scratch: per-query surviving-id tile
-    sem,        # DMA semaphore
-    *,
-    n_nodes: int,
-    expand_width: int,
-    metric: str,
-):
-    """Int8 variant of ``_expand_kernel``: gathers 1-byte codes + a 12-byte
-    metadata row per neighbor (quartering the dominant HBM gather term),
-    quantizes the query once per step, runs the R distances as ONE int8 x
-    int8 MXU matmul with an int32 accumulator, and dequantizes the
-    accumulator by ``scale_row * scale_query``. The emitted distances are
-    the certified lower bounds of ``core.corpus.lower_bound_dists`` — the
-    per-row stored error plus this kernel's own exact query-quantization
-    error — so the search loop's threshold tests stay supersets at the
-    caller's radius, identically to the XLA reference path."""
-    qi = pl.program_id(0)
-    e = pl.program_id(1)
-    i = qi * expand_width + e
-
-    @pl.when(e == 0)
-    def _reset_tile():
-        tile_ref[...] = jnp.full_like(tile_ref, INVALID_ID)
-
-    adj = adj_ref[0, :]                       # (R,) neighbor ids
-    n_ok = (adj >= 0) & (adj < n_nodes)
-    safe = jnp.where(n_ok, adj, 0)
-
-    def gather(r, _):
-        cp = pltpu.make_async_copy(codes_ref.at[safe[r]], cvec_ref.at[r], sem)
-        cp.start()
-        cp.wait()
-        cm = pltpu.make_async_copy(meta_ref.at[safe[r]], mvec_ref.at[r], sem)
-        cm.start()
-        cm.wait()
-        return 0
-
-    jax.lax.fori_loop(0, adj.shape[0], gather, 0)
-
-    # quantize the query (symmetric absmax, matching the corpus scheme)
-    q = q_ref[0, :].astype(jnp.float32)       # (d,)
-    q_scale = jnp.maximum(jnp.max(jnp.abs(q)), 1e-12) / 127.0
-    qc_f = jnp.clip(jnp.round(q / q_scale), -127, 127)
-    qc = qc_f.astype(jnp.int8)
-    q_err = jnp.sqrt(jnp.sum((q - qc_f * q_scale) ** 2))  # exact err_q
-
-    idot = jax.lax.dot_general(
-        cvec_ref[...], qc[:, None], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    )[:, 0]                                   # (R,) int32 MXU, exact
-    scales = mvec_ref[:, 0]                   # (R,)
-    errs = mvec_ref[:, 2]                     # (R,) per-row |x - x_hat|
-    dots = idot.astype(jnp.float32) * (scales * q_scale)
-    # certified lower bound (core.corpus.lower_bound_dists, inlined): the
-    # in-kernel distance is between DEQUANTIZED row and query, so both the
-    # row's stored error and this kernel's own query error are subtracted
-    if metric == "l2":
-        xn = mvec_ref[:, 1]
-        qn = jnp.sum((qc_f * q_scale) ** 2)
-        d_hat = jnp.maximum(xn + qn - 2.0 * dots, 0.0)
-        g = (errs + q_err) * (1.0 + GUARD_SLACK)
-        dist = jnp.maximum(jnp.sqrt(d_hat) - g, 0.0) ** 2
-    else:  # ip
-        q_norm = jnp.sqrt(jnp.sum(q * q))
-        xnorm = jnp.sqrt(jnp.maximum(mvec_ref[:, 1], 0.0))
-        eps = (errs * q_norm + xnorm * q_err) * (1.0 + GUARD_SLACK)
-        dist = -dots - eps
-
-    # dedup: earlier rows of this query's tile, then first-in-row wins
-    prev = tile_ref[...]                      # (E*R,)
-    seen_prev = jnp.any(adj[:, None] == prev[None, :], axis=1)
-    rr = jnp.arange(adj.shape[0])
-    dup_row = jnp.any(
-        (adj[:, None] == adj[None, :]) & (rr[None, :] < rr[:, None])
-        & n_ok[:, None] & n_ok[None, :],
-        axis=1,
-    )
-    f_ok = fval_ref[i] > 0
-    keep = n_ok & (~seen_prev) & (~dup_row) & f_ok
-
-    kept = jnp.where(keep, adj, INVALID_ID)
-    ids_ref[0, :] = kept
-    dist_ref[0, :] = jnp.where(keep, dist, jnp.inf)
-    cnt_ref[0, 0] = jnp.sum((n_ok & f_ok).astype(jnp.int32))
-    tile_ref[pl.ds(e * adj.shape[0], adj.shape[0])] = kept
+def expand_dists(points, ids, queries, *, metric: str = "l2",
+                 interpret: bool = False):
+    """(Q, T) distances of ``points[ids]`` to each query. ``ids`` (Q, T)
+    int32 must be pre-clamped to [0, N)."""
+    d = points.shape[1]
+    qn, t = ids.shape
+    return pl.pallas_call(
+        functools.partial(_dist_kernel, metric=metric),
+        grid=(qn,),
+        in_specs=[
+            pl.BlockSpec((1, 1, t), lambda i: (i, 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, d), lambda i: (i, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, 1, t), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((qn, 1, t), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((t, d), points.dtype),
+                        pltpu.SemaphoreType.DMA],
+        interpret=interpret,
+        name="expand_dists",
+    )(ids[:, None, :], queries[:, None, :], points)[:, 0]
 
 
-def expand_pallas_int8(
-    codes: jnp.ndarray,      # (N, d) int8 corpus codes
-    meta: jnp.ndarray,       # (N, 3) f32 [scale, |x_hat|^2, err]
-    neighbors: jnp.ndarray,  # (N, R) int32
-    fid: jnp.ndarray,        # (Q*E,) int32, pre-clamped to [0, N)
-    fval: jnp.ndarray,       # (Q*E,) int32 validity flags
-    queries: jnp.ndarray,    # (Q, d) f32
-    *,
-    expand_width: int,
-    metric: str = "l2",
-    interpret: bool = False,
-):
+def pack_int8_rows(codes: jnp.ndarray) -> jnp.ndarray:
+    """(N, d) int8 codes -> (ceil(N/4), D) int32, D = d rounded up to 128.
+
+    Byte k of word ``[i, c]`` is ``codes[4i + k, c]`` (zero-padded), so one
+    lane-aligned int32 row DMA fetches corpus row ``4i + k`` along with its
+    three neighbors. One elementwise pass over the codes; the search calls
+    it once per dispatch, outside its loop."""
     n, d = codes.shape
-    r = neighbors.shape[1]
-    qn = queries.shape[0]
-    e = expand_width
-    kernel = functools.partial(
-        _expand_kernel_int8, n_nodes=n, expand_width=e, metric=metric
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(qn, e),
-        in_specs=[
-            pl.BlockSpec((1, r), lambda qi, ei, fid_ref, fval_ref:
-                         (fid_ref[qi * e + ei], 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec((1, d), lambda qi, ei, fid_ref, fval_ref: (qi, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, r), lambda qi, ei, fid_ref, fval_ref: (qi, ei)),
-            pl.BlockSpec((1, r), lambda qi, ei, fid_ref, fval_ref: (qi, ei)),
-            pl.BlockSpec((1, 1), lambda qi, ei, fid_ref, fval_ref: (qi, ei)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((r, d), jnp.int8),
-            pltpu.VMEM((r, 3), jnp.float32),
-            pltpu.VMEM((e * r,), jnp.int32),
-            pltpu.SemaphoreType.DMA,
-        ],
-    )
-    ids, dists, cnts = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((qn, e * r), jnp.int32),
-            jax.ShapeDtypeStruct((qn, e * r), jnp.float32),
-            jax.ShapeDtypeStruct((qn, e), jnp.int32),
-        ],
-        interpret=interpret,
-    )(fid, fval, neighbors, codes, meta, queries)
-    return ids, dists, jnp.sum(cnts, axis=1)
+    n4 = -(-n // 4) * 4
+    lanes = -(-d // 128) * 128
+    c = jnp.pad(codes, ((0, n4 - n), (0, lanes - d))).astype(jnp.int32) & 0xFF
+    return c[0::4] | (c[1::4] << 8) | (c[2::4] << 16) | (c[3::4] << 24)
 
 
-def expand_pallas(
-    points: jnp.ndarray,     # (N, d)
-    neighbors: jnp.ndarray,  # (N, R) int32
-    fid: jnp.ndarray,        # (Q*E,) int32, pre-clamped to [0, N)
-    fval: jnp.ndarray,       # (Q*E,) int32 validity flags
-    queries: jnp.ndarray,    # (Q, d)
-    *,
-    expand_width: int,
-    metric: str = "l2",
-    interpret: bool = False,
-):
-    n, d = points.shape
-    r = neighbors.shape[1]
-    qn = queries.shape[0]
-    e = expand_width
-    kernel = functools.partial(
-        _expand_kernel, n_nodes=n, expand_width=e, metric=metric
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(qn, e),
+def _dist_int8_kernel(rows_ref, seg_ref, scale_ref, q_ref, packed_hbm,
+                      out_ref, vec_ref, sem, *, metric):
+    _gather_rows(rows_ref, packed_hbm, vec_ref, sem)
+    v = vec_ref[...]                          # (T, D) int32, 4 rows per word
+    seg = seg_ref[0]                          # (1, T) byte holding each id
+    scale = scale_ref[0]                      # (1, T) per-row code scale
+    q = q_ref[0]                              # (D, 1) f32 query column
+    out = jnp.zeros(seg.shape, jnp.float32)
+    for k in range(4):
+        # sign-extend byte k, dequantize in f32, reduce over sublanes
+        codes = jax.lax.shift_right_arithmetic(
+            jax.lax.shift_left(v, 24 - 8 * k), 24)
+        x = jnp.transpose(codes.astype(jnp.float32)) * scale   # (D, T)
+        if metric == "l2":
+            diff = x - q
+            d = jnp.sum(diff * diff, axis=0, keepdims=True)
+        else:  # ip
+            d = -jnp.sum(x * q, axis=0, keepdims=True)
+        out = out + jnp.where(seg == k, d, 0.0)
+    out_ref[0] = out
+
+
+def expand_dists_int8(packed, scales, ids, queries, *, metric: str = "l2",
+                      interpret: bool = False):
+    """(Q, T) distances of the dequantized rows ``codes[ids] * scales`` to
+    each f32 query: the quantity ``core.corpus.quantized_gather_lb`` bounds.
+
+    ``packed`` is ``pack_int8_rows(codes)``; ``scales`` (Q, T) are the rows'
+    code scales; ``ids`` (Q, T) int32 are pre-clamped to [0, N)."""
+    lanes = packed.shape[1]
+    qn, t = ids.shape
+    q = jnp.pad(queries.astype(jnp.float32),
+                ((0, 0), (0, lanes - queries.shape[1])))
+    return pl.pallas_call(
+        functools.partial(_dist_int8_kernel, metric=metric),
+        grid=(qn,),
         in_specs=[
-            pl.BlockSpec((1, r), lambda qi, ei, fid_ref, fval_ref:
-                         (fid_ref[qi * e + ei], 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec((1, d), lambda qi, ei, fid_ref, fval_ref: (qi, 0)),
+            pl.BlockSpec((1, 1, t), lambda i: (i, 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, t), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, 1, t), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, lanes, 1), lambda i: (i, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=[
-            pl.BlockSpec((1, r), lambda qi, ei, fid_ref, fval_ref: (qi, ei)),
-            pl.BlockSpec((1, r), lambda qi, ei, fid_ref, fval_ref: (qi, ei)),
-            pl.BlockSpec((1, 1), lambda qi, ei, fid_ref, fval_ref: (qi, ei)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((r, d), points.dtype),
-            pltpu.VMEM((e * r,), jnp.int32),
-            pltpu.SemaphoreType.DMA,
-        ],
-    )
-    ids, dists, cnts = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((qn, e * r), jnp.int32),
-            jax.ShapeDtypeStruct((qn, e * r), jnp.float32),
-            jax.ShapeDtypeStruct((qn, e), jnp.int32),
-        ],
+        out_specs=pl.BlockSpec((1, 1, t), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((qn, 1, t), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((t, lanes), jnp.int32),
+                        pltpu.SemaphoreType.DMA],
         interpret=interpret,
-    )(fid, fval, neighbors, points, queries)
-    return ids, dists, jnp.sum(cnts, axis=1)
+        name="expand_dists_int8",
+    )((ids // 4)[:, None, :], (ids % 4)[:, None, :],
+      scales.astype(jnp.float32)[:, None, :], q[:, :, None], packed)[:, 0]

@@ -1,9 +1,9 @@
-"""jit'd public wrapper for the expand kernel (clamp + dispatch).
+"""jit'd public wrapper for the expand kernel.
 
-``use_pallas=False`` routes to the pure-jnp oracle — the XLA path the search
-loop uses on hosts where Pallas TPU custom calls do not lower (CPU CI, dry
-runs). On a real TPU set ``use_pallas=True, interpret=False``; for kernel
-unit tests ``interpret=True`` emulates the DMAs on CPU.
+``use_pallas=False`` runs the pure-jnp reference, which is what the search
+loop runs by default on every platform (``SearchConfig.use_expand_kernel``
+is off). ``use_pallas=True`` runs the Pallas kernels, compiled for the TPU;
+``interpret=True`` emulates them on CPU for the kernel tests.
 """
 from __future__ import annotations
 
@@ -12,8 +12,20 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from .kernel import expand_pallas, expand_pallas_int8
-from .ref import expand_frontier_ref
+from .kernel import expand_dists, expand_dists_int8, pack_int8_rows
+from .ref import expand_frontier_1, expand_frontier_ref
+
+
+def _int8_lower_bounds(corpus, packed, safe, q, metric, interpret):
+    """Kernel distances of the dequantized rows -> certified lower bounds,
+    exactly as ``core.corpus.quantized_gather_lb`` bounds the XLA ones."""
+    from ...core.corpus import lower_bound_dists
+    qf = q.astype(jnp.float32)
+    meta = jnp.take(corpus.meta, safe, axis=0)            # (T, 3)
+    d_hat = expand_dists_int8(packed, meta[None, :, 0], safe[None], qf[None],
+                              metric=metric, interpret=interpret)[0]
+    return lower_bound_dists(meta, d_hat, jnp.float32(0.0),
+                             jnp.sqrt(jnp.sum(qf * qf)), metric)
 
 
 @partial(jax.jit, static_argnames=("metric", "use_pallas", "interpret"))
@@ -25,7 +37,8 @@ def expand_frontier(
     *,
     metric: str = "l2",
     use_pallas: bool = True,
-    interpret: bool = True,
+    interpret: bool = False,
+    packed=None,             # pack_int8_rows(points.codes), if precomputed
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Fused frontier expansion.
 
@@ -34,27 +47,22 @@ def expand_frontier(
     ``n_dist`` counts distances computed (pre-dedup).
 
     A quantized corpus (duck-typed via ``.codes``) routes to the int8
-    kernel: int8 code gather + int8 MXU matmul + accumulator dequant. The
-    kernel quantizes the query too, so its distances differ from the XLA
-    reference's (which keeps the query in f32) by at most the
-    ``query_quant_err`` term of the guard-band envelope.
+    kernel, which returns the same certified lower bounds as the XLA
+    reference. The search loop passes ``packed`` once per dispatch; without
+    it the codes are packed here, on every call.
     """
-    quant = getattr(points, "codes", None) is not None
     if not use_pallas:
         return expand_frontier_ref(points, neighbors, frontier, queries,
                                    metric=metric)
-    n = (points.codes if quant else points).shape[0]
-    qn, e = frontier.shape
-    f_ok = (frontier >= 0) & (frontier < n)
-    fid = jnp.where(f_ok, frontier, 0).reshape(-1)
-    fval = f_ok.astype(jnp.int32).reshape(-1)
-    if quant:
-        return expand_pallas_int8(
-            points.codes, points.meta, neighbors, fid, fval, queries,
-            expand_width=e, metric=metric, interpret=interpret,
-        )
-    ids, dists, cnts = expand_pallas(
-        points, neighbors, fid, fval, queries,
-        expand_width=e, metric=metric, interpret=interpret,
-    )
-    return ids, dists, cnts
+    if getattr(points, "codes", None) is not None:
+        if packed is None:
+            packed = pack_int8_rows(points.codes)
+        dists_fn = partial(_int8_lower_bounds, points, packed, metric=metric,
+                           interpret=interpret)
+    else:
+        dists_fn = lambda safe, q: expand_dists(
+            points, safe[None], q[None], metric=metric,
+            interpret=interpret)[0]
+    fn = lambda f, q: expand_frontier_1(points, neighbors, f, q, metric,
+                                        dists_fn=dists_fn)
+    return jax.vmap(fn)(frontier, queries)

@@ -9,6 +9,9 @@ Semantics (shared with the Pallas kernel):
   included, matching the unfused path's accounting);
 * only the **first occurrence** of each neighbor id within the flattened
   E*R tile survives; later duplicates are masked to INVALID/+inf.
+
+The Pallas path (``ops.expand_frontier``) runs this same function with the
+kernel as its distance backend.
 """
 from __future__ import annotations
 
@@ -26,15 +29,13 @@ def expand_frontier_1(
     frontier: jnp.ndarray,   # (E,) int32 nodes to expand (INVALID_ID padded)
     q: jnp.ndarray,          # (d,) query
     metric: str = "l2",
-    point_norms: jnp.ndarray | None = None,  # (N,) precomputed |x|^2 (l2)
+    dists_fn=None,
 ):
     """Single-query fused expansion -> (ids (E*R,), dists (E*R,), n_dist ()).
 
-    Distances use the kernel's matmul form, ``|x|^2 + |q|^2 - 2 x.q``, when
-    ``point_norms`` is supplied (the search loop precomputes them once per
-    corpus): one (T, d) x (d,) GEMV plus a T-float norm gather replaces
-    three elementwise passes over the gathered tile — the tile read is the
-    loop's bandwidth floor, so passes over it are what matter.
+    ``dists_fn(safe_ids (E*R,), q) -> (E*R,)`` computes the candidate
+    distances; the default is the XLA gather below, and ``ops`` passes the
+    Pallas kernel. Masks and dedup are the same for both.
 
     An int8 quantized corpus gathers 1-byte codes + a 12-byte metadata row
     per candidate (the ~4x HBM saving), dequantizes in-register, and
@@ -50,25 +51,19 @@ def expand_frontier_1(
 
     valid = (flat >= 0) & (flat < n)
     safe = jnp.where(valid, flat, 0)
-    qf = q.astype(jnp.float32)
-    if quant:
+    if dists_fn is not None:
+        d = dists_fn(safe, q)
+    elif quant:
         from ...core.corpus import quantized_gather_lb
-        d = quantized_gather_lb(points, safe, qf, metric)
-        dup = _first_occurrence_dup(flat, valid)
-        keep = valid & ~dup
-        ids = jnp.where(keep, flat, INVALID_ID)
-        dists = jnp.where(keep, d, jnp.inf)
-        return ids, dists, jnp.sum(valid).astype(jnp.int32)
-    vecs = jnp.take(points, safe, axis=0).astype(jnp.float32)  # (E*R, d)
-    if metric == "l2" and point_norms is not None:
-        dots = vecs @ qf
-        xn = jnp.take(point_norms, safe).astype(jnp.float32)
-        d = jnp.maximum(xn + jnp.sum(qf * qf) - 2.0 * dots, 0.0)
-    elif metric == "l2":
-        diff = vecs - qf[None, :]
-        d = jnp.sum(diff * diff, axis=-1)
-    else:  # ip
-        d = -(vecs @ qf)
+        d = quantized_gather_lb(points, safe, q.astype(jnp.float32), metric)
+    else:
+        qf = q.astype(jnp.float32)
+        vecs = jnp.take(points, safe, axis=0).astype(jnp.float32)  # (E*R, d)
+        if metric == "l2":
+            diff = vecs - qf[None, :]
+            d = jnp.sum(diff * diff, axis=-1)
+        else:  # ip
+            d = -(vecs @ qf)
 
     dup = _first_occurrence_dup(flat, valid)
     keep = valid & ~dup
@@ -78,10 +73,10 @@ def expand_frontier_1(
 
 
 def _first_occurrence_dup(flat: jnp.ndarray, valid: jnp.ndarray) -> jnp.ndarray:
-    """First-occurrence dedup as one vectorized (T, T) compare — the same
-    one-pass mask the kernel computes. (A sort-based O(T log T) dedup was
-    tried and lost in-loop: XLA's sort comparator costs far more per
-    element than a broadcast compare at tile sizes of a few hundred.)"""
+    """First-occurrence dedup as one vectorized (T, T) compare. (A
+    sort-based O(T log T) dedup was tried and lost in-loop: XLA's sort
+    comparator costs far more per element than a broadcast compare at tile
+    sizes of a few hundred.)"""
     t = jnp.arange(flat.shape[0])
     return jnp.any(
         (flat[:, None] == flat[None, :])

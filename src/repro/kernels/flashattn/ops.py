@@ -26,7 +26,7 @@ def flash_attention(
     block_q: int = 128,
     block_k: int = 128,
     use_pallas: bool = True,
-    interpret: bool = True,  # CPU default; set False on real TPU
+    interpret: bool = False,
 ) -> jnp.ndarray:
     if not use_pallas:
         return flash_attention_ref(q, k, v, causal=causal, window=window,

@@ -19,7 +19,7 @@ def gatherdist(
     *,
     metric: str = "l2",
     use_pallas: bool = True,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """(Q, R) fused gather+distance; invalid ids map to +inf.
 

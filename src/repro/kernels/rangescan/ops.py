@@ -22,7 +22,7 @@ def rangescan(
     block_n: int = 512,
     metric: str = "l2",
     use_pallas: bool = True,
-    interpret: bool = True,  # CPU default; set False on real TPU
+    interpret: bool = False,
 ):
     """Fused exact range scan: (ids (Q,k), dists (Q,k), counts (Q,)).
 

@@ -1,10 +1,10 @@
 """Dispatch wrapper for the rerank-fetch kernel.
 
-`use_pallas=False` (the CPU-CI default) runs the XLA reference;
-`use_pallas=True, interpret=True` emulates the TPU kernel on CPU for the
-parity suite. The tiered corpus's host path does not route through here —
-on CPU CI the host→device copy is a `jax.device_put` — but on TPU this is
-the fetch+distance stage the tier swaps in per miss bucket.
+`use_pallas=False` runs the XLA reference; `use_pallas=True,
+interpret=True` emulates the kernel on CPU for the parity suite. The TPU
+compiler refuses the kernel (its `(1, 16)` output block on a `(16, 16)`
+array), and nothing calls this wrapper: the tiered corpus fetches its rows
+with `jax.device_put` on every platform.
 """
 from __future__ import annotations
 

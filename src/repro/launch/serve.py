@@ -40,7 +40,102 @@ from ..core.radius import default_grid, select_radius, sweep
 from ..data.synthetic import make_corpus
 from ..live import LiveConfig, LiveIndex
 from ..serve import RangeServer, Request, ServerConfig
-from ..utils import INVALID_ID
+from ..utils import INVALID_ID, enable_compile_cache
+
+
+def select_serving_radius(points, queries, metric: str):
+    """The paper's Sec.-3 radius methodology over a 24-point grid: returns
+    ``(radius, grid_index, profile)``."""
+    grid = default_grid(points, queries, metric, num=24)
+    prof = sweep(jnp.asarray(points), jnp.asarray(queries), grid, metric)
+    r, gi = select_radius(prof, robustness_weight=0.2)
+    print(f"[serve] selected radius {r:.4g} "
+          f"(zero-result frac {prof.zero_frac[gi]:.2f})")
+    return r, gi, prof
+
+
+# A quantized search buffers every candidate whose certified lower bound is
+# in range (the keep band), a superset of the answer that the rerank trims
+# afterwards. On bigann-like the band held 1.50x (100k rows) and 1.66x
+# (300k) the true matches, so an int8 buffer sized like the f32 one filled
+# up on the heaviest queries and lost matches f32 keeps.
+QUANTIZED_CAP_HEADROOM = 2
+
+
+def serving_range_cfg(metric: str, *, beam: int = 32, expand_width: int = 4,
+                      corpus_dtype: str = "float32", mode: str = "greedy",
+                      early_stop: bool = False,
+                      use_expand_kernel: bool = False):
+    """The ``RangeConfig`` every serving driver runs with: room for 2048
+    results per query, times ``QUANTIZED_CAP_HEADROOM`` on an int8
+    corpus."""
+    cap = 2048 * (QUANTIZED_CAP_HEADROOM if corpus_dtype == "int8" else 1)
+    return EngineDeployConfig().overrides(
+        metric=metric, beam=beam,
+        max_beam=beam * (8 if mode == "doubling" else 1),
+        visit_cap=512,
+        es_metric=ES_D_VISITED if early_stop else 0, es_visit_limit=20,
+        expand_width=expand_width, corpus_dtype=corpus_dtype,
+        use_expand_kernel=use_expand_kernel,
+        mode=mode, result_cap=cap).range_cfg
+
+
+def serve_lockstep(srv: RangeServer, points, queries, radii, *,
+                   metric: str, filt_of=None, fmode=None,
+                   raw_labels=None) -> dict:
+    """Serve each query once through ``srv`` (submit, step under
+    backpressure, drain) and score AP against ``exact_range_search`` over
+    ``points``, the exact f32 corpus.
+
+    ``filt_of[i]``/``fmode[i]`` give request i's label predicate (None for
+    unfiltered); filtered lanes score against the post-filtered oracle over
+    ``raw_labels``. Returns the numbers: ``seconds`` and ``qps`` of the
+    serving loop, ``ap``, ``latency_ms`` (sorted per request), the
+    per-query result ids (``ids``, sorted) and the ``responses``."""
+    nq = len(queries)
+    filt_of = filt_of or [None] * nq
+    fmode = fmode or ["and"] * nq
+    t0 = time.perf_counter()
+    resp = []
+    for i in range(nq):
+        rq = Request(req_id=i, query=queries[i], radius=float(radii[i]),
+                     filter_labels=filt_of[i], filter_mode=fmode[i])
+        while srv.submit(rq) is not None:  # queue_full: serve under
+            resp.extend(srv.step())        # backpressure, then retry
+    resp.extend(srv.run_until_drained())
+    dt = time.perf_counter() - t0
+
+    gt_ids, _, gt_counts = exact_range_search(
+        jnp.asarray(points), jnp.asarray(queries), jnp.asarray(radii), metric)
+    if raw_labels is not None:
+        # filtered lanes score against the POST-FILTERED oracle: the exact
+        # in-radius set restricted to predicate-matching points
+        gt_ids = np.asarray(gt_ids).copy()
+        gt_counts = np.asarray(gt_counts).copy()
+        lab_sets = [set(l) for l in raw_labels]
+        for qi in range(nq):
+            if filt_of[qi] is None:
+                continue
+            pred = set(filt_of[qi])
+            keep = [int(x) for x in gt_ids[qi][:gt_counts[qi]]
+                    if (pred <= lab_sets[int(x)] if fmode[qi] == "and"
+                        else bool(pred & lab_sets[int(x)]))]
+            gt_ids[qi] = INVALID_ID
+            gt_ids[qi, :len(keep)] = keep
+            gt_counts[qi] = len(keep)
+    res_ids = np.full((nq, 4096), INVALID_ID, np.int64)
+    counts = np.zeros(nq, np.int64)
+    ids = [None] * nq
+    for rp in resp:
+        k = min(len(rp.ids), 4096)
+        res_ids[rp.req_id, :k] = rp.ids[:k]
+        counts[rp.req_id] = k
+        ids[rp.req_id] = np.sort(np.asarray(rp.ids))
+    ap = average_precision(np.asarray(gt_ids), np.asarray(gt_counts),
+                           res_ids, counts)
+    return dict(seconds=dt, qps=nq / dt, ap=float(ap), ids=ids,
+                latency_ms=sorted(rp.latency_s * 1e3 for rp in resp),
+                responses=resp)
 
 
 def _replicated_main(args) -> int:
@@ -57,11 +152,7 @@ def _replicated_main(args) -> int:
     pts = np.asarray(ds.points, np.float32)
     qs = ds.queries
 
-    grid = default_grid(ds.points, ds.queries, ds.metric, num=24)
-    prof = sweep(jnp.asarray(pts), jnp.asarray(qs), grid, ds.metric)
-    r, gi = select_radius(prof, robustness_weight=0.2)
-    print(f"[serve] selected radius {r:.4g} "
-          f"(zero-result frac {prof.zero_frac[gi]:.2f})")
+    r, _, _ = select_serving_radius(pts, qs, ds.metric)
 
     bcfg = BuildConfig(max_degree=32, beam=64, metric=ds.metric)
     t0 = time.perf_counter()
@@ -85,39 +176,20 @@ def _replicated_main(args) -> int:
     hedge = (HedgePolicy(delay_s=args.hedge_ms / 1e3)
              if args.hedge_ms > 0 else None)
 
-    rcfg = EngineDeployConfig().overrides(
-        metric=ds.metric,
-        beam=args.beam, max_beam=args.beam, visit_cap=512,
-        expand_width=args.expand_width, corpus_dtype=args.corpus_dtype,
-        mode=args.mode, result_cap=2048).range_cfg
+    rcfg = serving_range_cfg(ds.metric, beam=args.beam,
+                             expand_width=args.expand_width,
+                             corpus_dtype=args.corpus_dtype, mode=args.mode)
     srv = RangeServer(None, rcfg, ServerConfig(max_batch=args.max_batch),
                       sharded=corpus, replicas=args.replicas,
                       injector=injector, hedge=hedge,
                       retry=RetryPolicy(backoff_s=0.01))
 
-    t0 = time.perf_counter()
-    resp = []
-    for i in range(args.queries):
-        rq = Request(req_id=i, query=qs[i], radius=float(r))
-        while srv.submit(rq) is not None:
-            resp.extend(srv.step())
-    resp.extend(srv.run_until_drained())
-    dt = time.perf_counter() - t0
-
-    gt_ids, _, gt_counts = exact_range_search(
-        jnp.asarray(pts), jnp.asarray(qs), float(r), ds.metric)
-    res_ids = np.full((args.queries, 4096), 2**31 - 1, np.int64)
-    counts = np.zeros(args.queries, np.int64)
-    for rp in resp:
-        k = min(len(rp.ids), 4096)
-        res_ids[rp.req_id, :k] = rp.ids[:k]
-        counts[rp.req_id] = k
-    ap = average_precision(np.asarray(gt_ids), np.asarray(gt_counts),
-                           res_ids, counts)
-    cov = min(rp.coverage for rp in resp)
-    codes = {rp.code for rp in resp}
-    print(f"[serve] {args.queries} queries in {dt:.3f}s = "
-          f"{args.queries / dt:.0f} QPS; AP={ap:.4f}; "
+    out = serve_lockstep(srv, pts, qs, np.full(args.queries, r, np.float32),
+                         metric=ds.metric)
+    cov = min(rp.coverage for rp in out["responses"])
+    codes = {rp.code for rp in out["responses"]}
+    print(f"[serve] {args.queries} queries in {out['seconds']:.3f}s = "
+          f"{out['qps']:.0f} QPS; AP={out['ap']:.4f}; "
           f"min coverage={cov:.2f} codes={codes}")
     st = srv.stats
     print(f"[serve] replication: hedges_fired={st['hedges_fired']} "
@@ -155,11 +227,7 @@ def _churn_main(args) -> int:
         print(f"[serve] labeled live corpus: {args.num_labels}-label "
               f"vocabulary, 1-3 labels/point (inserts carry labels)")
 
-    grid = default_grid(init, ds.queries, ds.metric, num=24)
-    prof = sweep(jnp.asarray(init), jnp.asarray(qs), grid, ds.metric)
-    r, gi = select_radius(prof, robustness_weight=0.2)
-    print(f"[serve] selected radius {r:.4g} "
-          f"(zero-result frac {prof.zero_frac[gi]:.2f})")
+    r, _, _ = select_serving_radius(init, qs, ds.metric)
 
     t0 = time.perf_counter()
     live = LiveIndex.create(
@@ -175,11 +243,9 @@ def _churn_main(args) -> int:
         print(f"[serve] tiered live corpus: "
               f"{live.points.budget().as_dict()}")
 
-    rcfg = EngineDeployConfig().overrides(
-        metric=ds.metric,
-        beam=args.beam, max_beam=args.beam, visit_cap=512,
-        expand_width=args.expand_width, corpus_dtype=args.corpus_dtype,
-        mode=args.mode, result_cap=2048).range_cfg
+    rcfg = serving_range_cfg(ds.metric, beam=args.beam,
+                             expand_width=args.expand_width,
+                             corpus_dtype=args.corpus_dtype, mode=args.mode)
     srv = RangeServer(None, rcfg,
                       ServerConfig(max_batch=args.max_batch,
                                    continuous=args.continuous,
@@ -349,6 +415,7 @@ def main(argv=None):
                    help="scripted replica loss, e.g. '0:0,1:1' downs shard "
                         "0's replica 0 and shard 1's replica 1")
     args = p.parse_args(argv)
+    enable_compile_cache()
     if args.tier:
         args.corpus_dtype = "int8"  # tiering exists for the quantized split
 
@@ -361,12 +428,7 @@ def main(argv=None):
     ds = make_corpus(args.profile, n=args.n, n_queries=args.queries)
     pts = jnp.asarray(ds.points)
     qs = ds.queries
-
-    grid = default_grid(ds.points, ds.queries, ds.metric, num=24)
-    prof = sweep(pts, jnp.asarray(qs), grid, ds.metric)
-    r, gi = select_radius(prof, robustness_weight=0.2)
-    print(f"[serve] selected radius {r:.4g} "
-          f"(zero-result frac {prof.zero_frac[gi]:.2f})")
+    r, gi, prof = select_serving_radius(ds.points, qs, ds.metric)
 
     raw_labels = None
     labels_packed = None
@@ -432,16 +494,10 @@ def main(argv=None):
         print(f"[serve] filtered traffic: {nf}/{args.queries} requests "
               f"carry label predicates")
 
-    rcfg = EngineDeployConfig().overrides(
-        metric=ds.metric,
-        beam=args.beam,
-        max_beam=args.beam * (8 if args.mode == "doubling" else 1),
-        visit_cap=512,
-        es_metric=ES_D_VISITED if args.early_stop else 0,
-        es_visit_limit=20,
-        expand_width=args.expand_width,
-        corpus_dtype=args.corpus_dtype,
-        mode=args.mode, result_cap=2048).range_cfg
+    rcfg = serving_range_cfg(
+        ds.metric, beam=args.beam, expand_width=args.expand_width,
+        corpus_dtype=args.corpus_dtype, mode=args.mode,
+        early_stop=args.early_stop)
     effort = None
     if args.effort:
         # calibrate the admission regressor on exact match counts for a
@@ -460,48 +516,13 @@ def main(argv=None):
                                    lanes=args.lanes,
                                    slice_rounds=args.slice_rounds),
                       effort=effort)
-    t0 = time.perf_counter()
-    resp = []
-    for i in range(args.queries):
-        rq = Request(req_id=i, query=qs[i], radius=float(radii[i]),
-                     filter_labels=filt_of[i], filter_mode=fmode[i])
-        while srv.submit(rq) is not None:  # queue_full: serve under
-            resp.extend(srv.step())        # backpressure, then retry
-    resp.extend(srv.run_until_drained())
-    dt = time.perf_counter() - t0
-    qps = args.queries / dt
-
-    gt_ids, _, gt_counts = exact_range_search(pts, jnp.asarray(qs),
-                                              jnp.asarray(radii), ds.metric)
-    if args.filter_frac > 0:
-        # filtered lanes score against the POST-FILTERED oracle: the exact
-        # in-radius set restricted to predicate-matching points
-        gt_ids = np.asarray(gt_ids).copy()
-        gt_counts = np.asarray(gt_counts).copy()
-        lab_sets = [set(l) for l in raw_labels]
-        for qi in range(args.queries):
-            if filt_of[qi] is None:
-                continue
-            pred = set(filt_of[qi])
-            keep = [int(x) for x in gt_ids[qi][:gt_counts[qi]]
-                    if (pred <= lab_sets[int(x)] if fmode[qi] == "and"
-                        else bool(pred & lab_sets[int(x)]))]
-            gt_ids[qi] = INVALID_ID
-            gt_ids[qi, :len(keep)] = keep
-            gt_counts[qi] = len(keep)
-    res_ids = np.full((args.queries, 4096), 2**31 - 1, np.int64)
-    counts = np.zeros(args.queries, np.int64)
-    for rp in resp:
-        k = min(len(rp.ids), 4096)
-        res_ids[rp.req_id, :k] = rp.ids[:k]
-        counts[rp.req_id] = k
-    ap = average_precision(np.asarray(gt_ids), np.asarray(gt_counts),
-                           res_ids, counts)
-    lat = sorted(rp.latency_s for rp in resp)
-    print(f"[serve] {args.queries} queries in {dt:.3f}s = {qps:.0f} QPS "
-          f"(batched); AP={ap:.4f}")
-    print(f"[serve] latency p50={lat[len(lat)//2]*1e3:.1f}ms "
-          f"p99={lat[int(len(lat)*0.99)]*1e3:.1f}ms; stats={srv.stats}")
+    out = serve_lockstep(srv, pts, qs, radii, metric=ds.metric,
+                         filt_of=filt_of, fmode=fmode, raw_labels=raw_labels)
+    lat = out["latency_ms"]
+    print(f"[serve] {args.queries} queries in {out['seconds']:.3f}s = "
+          f"{out['qps']:.0f} QPS (batched); AP={out['ap']:.4f}")
+    print(f"[serve] latency p50={lat[len(lat)//2]:.1f}ms "
+          f"p99={lat[int(len(lat)*0.99)]:.1f}ms; stats={srv.stats}")
     hs = srv.latency_summary()
     print(f"[serve] histogram p50/p95/p99 (ms): "
           + " ".join(f"{op}={h['p50_ms']:.1f}/{h['p95_ms']:.1f}/{h['p99_ms']:.1f}"

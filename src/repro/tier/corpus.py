@@ -194,9 +194,8 @@ class TieredCorpus:
 
         # Double-buffered streaming of the miss buckets: the host→device
         # copy for bucket i+1 is issued (async dispatch) while bucket i's
-        # device-side scatter runs. On CPU CI this is `jax.device_put`
-        # overlap; the TPU path swaps in kernels/rerank_fetch's manual-DMA
-        # pipeline against the same plan.
+        # device-side scatter runs (`jax.device_put` overlap, on every
+        # platform).
         miss_pos = np.nonzero(~plan.hit_mask)[0].astype(np.int32)
         chunks = plan.miss_chunks
         nxt = jax.device_put(self.store.gather(chunks[0])) if chunks else None

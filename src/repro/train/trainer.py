@@ -50,6 +50,12 @@ class Trainer:
         grad_transform=None,
     ):
         self.cfg = cfg
+        if mesh is not None:
+            # the step relies on GSPMD propagation from its in/out shardings
+            # (Auto axes); jax.make_mesh now defaults to Explicit axes
+            mesh = jax.sharding.Mesh(
+                mesh.devices, mesh.axis_names,
+                axis_types=(jax.sharding.AxisType.Auto,) * len(mesh.axis_names))
         self.mesh = mesh
         self.ckpt = CheckpointManager(cfg.ckpt_dir, keep=cfg.keep_ckpts)
         self.params = params

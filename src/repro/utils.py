@@ -1,6 +1,7 @@
 """Small shared utilities: padding, pytree helpers, timing."""
 from __future__ import annotations
 
+import os
 import time
 from functools import partial
 from typing import Any, Callable
@@ -14,6 +15,25 @@ import numpy as np
 # padded entries at the *end* of ascending id orderings.
 INVALID_ID = np.int32(2**31 - 1)
 INF = np.float32(np.inf)
+
+# the checkout's own compile-cache directory (listed in .gitignore)
+REPO_COMPILE_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    nothing is overridden. Otherwise the cache goes to ``.jax_cache`` at
+    the root of the checkout: a fixed path, because the directory is part
+    of what a later process must find again."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = REPO_COMPILE_CACHE
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def cdiv(a: int, b: int) -> int:

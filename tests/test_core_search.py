@@ -266,6 +266,21 @@ def test_radius_selection_hits_target(target):
     assert all(b <= a + 1e-9 for a, b in zip(zf, zf[1:]))
 
 
+@pytest.mark.parametrize("n", [50_000, 400_000])
+def test_default_grid_low_edge_keeps_its_match_count(n):
+    """Regression: the grid's low edge was a fixed quantile of query-to-point
+    distances, so the matches a query expects there grew with the corpus
+    (about 500 at 1M rows) and radius selection fell onto the capture
+    plateau. It now stays near GRID_LO_MATCHES at every size."""
+    from repro.core.radius import GRID_LO_MATCHES
+    rng = np.random.default_rng(4)
+    pts = rng.standard_normal((n, 8)).astype(np.float32)
+    qs = rng.standard_normal((256, 8)).astype(np.float32)
+    grid = default_grid(pts, qs, "l2", num=8)
+    mean = sweep(jnp.asarray(pts), jnp.asarray(qs), grid[:1]).counts.mean()
+    assert GRID_LO_MATCHES / 2 <= mean <= 2 * GRID_LO_MATCHES, mean
+
+
 def test_match_histogram_buckets():
     h = match_histogram(np.array([0, 0, 3, 11, 99, 1000, 99999]))
     assert h["0"] == 2 and h["<=1e1"] == 1 and h["<=1e2"] == 2

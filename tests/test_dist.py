@@ -28,7 +28,7 @@ def test_compressed_psum_and_collective_matmul():
     run_sub("""
         import numpy as np, jax, jax.numpy as jnp
         from functools import partial
-        from repro.dist.compat import shard_map  # jax<0.6: no jax.shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.dist.compression import compressed_psum_mean
         from repro.dist.collective_matmul import allgather_matmul, matmul_reducescatter
@@ -175,6 +175,53 @@ def test_sharded_matches_host_union_exactly():
             assert set(got_ids[q, :k]) == set(ids[q, :k]), q
             assert (got_ids[q, k:] == INVALID_ID).all()
         assert int(want_count.sum()) > 0  # the check is not vacuous
+        print("OK")
+    """)
+
+
+def test_build_sharded_places_one_shard_per_device():
+    """build_sharded(mesh=) leaves every device holding only its own shard
+    (replicated along the data axis), and both fan-outs over that placed
+    corpus agree per query: the shard_map program behind
+    RangeServer(mesh=, sharded=) and the host fan-out, whose per-shard
+    searches run on the shard's own device."""
+    run_sub("""
+        import numpy as np, jax, jax.numpy as jnp
+        from repro.core import RangeConfig, SearchConfig, build_knn_graph
+        from repro.core.graph import medoid
+        from repro.dist.sharded_engine import build_sharded, shard_view
+        from repro.fault import fault_tolerant_sharded_search
+        from repro.serve import RangeServer, Request, ServerConfig
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
+        pts = np.random.default_rng(3).standard_normal((1600, 8)).astype(np.float32)
+        qs = pts[:16] + 0.02
+        rcfg = RangeConfig(search=SearchConfig(beam=16, max_beam=16,
+                                               visit_cap=64, expand_width=2),
+                           mode="greedy", result_cap=128)
+        corpus = build_sharded(pts, 4, lambda p: (build_knn_graph(p, k=8),
+                                                  medoid(p)[None]), mesh=mesh)
+        for leaf in (corpus.points, corpus.neighbors, corpus.start_ids):
+            for piece in leaf.addressable_shards:
+                m = int(np.argwhere(mesh.devices == piece.device)[0, 1])
+                assert piece.data.shape[0] == 1
+                assert range(4)[piece.index[0]] == range(m, m + 1), (m, piece.index)
+        for s in range(4):  # the host fan-out reads each shard in place
+            assert shard_view(corpus.points, s).devices() == \
+                {mesh.devices[0, s]}
+        srv = RangeServer(None, rcfg, ServerConfig(max_batch=16), mesh=mesh,
+                          sharded=corpus)
+        for i in range(16):
+            srv.submit(Request(req_id=i, query=qs[i], radius=2.5))
+        got = {rp.req_id: np.sort(rp.ids) for rp in srv.run_until_drained()}
+        ref = fault_tolerant_sharded_search(corpus=corpus, queries=qs, r=2.5,
+                                            cfg=rcfg).result
+        total = 0
+        for i in range(16):
+            want = np.sort(np.asarray(ref.ids[i][:ref.count[i]]))
+            np.testing.assert_array_equal(got[i], want)
+            total += len(want)
+        assert total > 0  # the check is not vacuous
         print("OK")
     """)
 
