@@ -1,0 +1,8 @@
+"""Device-idle time inside the server's range.step spans, in ms per step:
+the host path of a lockstep step seen from inside the program (the inside
+twin of host_ms_per_batch)."""
+from bench import spans
+
+
+def read(ctx):
+    return spans.idle_ms_per_step(ctx, "range.step")

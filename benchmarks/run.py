@@ -1196,7 +1196,7 @@ def main(argv=None):
 
     from . import (
         early_stop_metrics, early_stop_qps, kernel_bench, match_distribution,
-        qps_precision, radius_capture, time_breakdown, topk_compare,
+        qps_precision, radius_capture, topk_compare,
     )
 
     t0 = time.time()
@@ -1207,7 +1207,6 @@ def main(argv=None):
     qps_precision.run(n=args.n, quick=quick)
     early_stop_metrics.run(n=args.n, quick=quick)
     early_stop_qps.run(n=args.n, quick=quick)
-    time_breakdown.run(n=args.n)
     topk_compare.run(n=args.n)
     kernel_bench.run()
     if args.scale:

@@ -558,12 +558,14 @@ def beam_search_batch(
     n = queries.shape[0]
     rv = broadcast_radius(r, n)
     esv = broadcast_radius(es_radius, n)
-    if start_ids.ndim == 2:
-        fn = lambda q, s_, r_, es_: beam_search(points, graph, q, s_, r_,
-                                                cfg, es_)
-        return jax.vmap(fn)(queries, start_ids, rv, esv)
-    fn = lambda q, r_, es_: beam_search(points, graph, q, start_ids, r_, cfg, es_)
-    return jax.vmap(fn)(queries, rv, esv)
+    with jax.named_scope("range.phase1"):
+        if start_ids.ndim == 2:
+            fn = lambda q, s_, r_, es_: beam_search(points, graph, q, s_, r_,
+                                                    cfg, es_)
+            return jax.vmap(fn)(queries, start_ids, rv, esv)
+        fn = lambda q, r_, es_: beam_search(points, graph, q, start_ids, r_,
+                                            cfg, es_)
+        return jax.vmap(fn)(queries, rv, esv)
 
 
 def topk_from_state(st: BeamState, k: int):

@@ -95,6 +95,9 @@ class RangeResult:
     es_stopped: jnp.ndarray  # (Q,) bool
     phase2: jnp.ndarray    # (Q,) bool — query took the second phase
     n_rerank: jnp.ndarray  # (Q,) int32 — guard-band candidates exact-reranked
+    # (Q,) int32 host array — greedy phase-2 expansions per lane, -1 where
+    # the lane stopped at phase 1; None on paths that do not track them
+    p2_rounds: Optional[np.ndarray] = None
 
 
 # ---------------------------------------------------------------------------
@@ -331,14 +334,15 @@ def greedy_search(
     ``expand_ptr`` by up to ``scfg.expand_width`` and charges that many
     rounds (the last iteration may overshoot by at most E - 1).
     """
-    r = jnp.asarray(r, jnp.float32)
-    n_corpus = corpus_size(points)
-    num_words = bitset_num_words(n_corpus, scfg.bitset_cap_bits)
-    exact_bits = bitset_exact(n_corpus, num_words)
-    gs = _greedy_init(st, r, cap, num_words, exact_bits)
-    gs = _greedy_run(points, graph, q, r, gs, cap, rounds, scfg, active)
-    gs = dataclasses.replace(gs, overflow=gs.overflow | (gs.expand_ptr < gs.res_count))
-    return gs
+    with jax.named_scope("range.phase2"):
+        r = jnp.asarray(r, jnp.float32)
+        n_corpus = corpus_size(points)
+        num_words = bitset_num_words(n_corpus, scfg.bitset_cap_bits)
+        exact_bits = bitset_exact(n_corpus, num_words)
+        gs = _greedy_init(st, r, cap, num_words, exact_bits)
+        gs = _greedy_run(points, graph, q, r, gs, cap, rounds, scfg, active)
+        return dataclasses.replace(
+            gs, overflow=gs.overflow | (gs.expand_ptr < gs.res_count))
 
 
 # ---------------------------------------------------------------------------
@@ -392,7 +396,8 @@ def greedy_resume_batch(
         stop_at = jnp.minimum(g_.rounds + slice_rounds, rounds)
         return _greedy_run(corpus, graph, q_, r_, g_, cap, stop_at, scfg, a_)
 
-    return jax.vmap(one)(queries, rj, gs, active)
+    with jax.named_scope("range.phase2"):
+        return jax.vmap(one)(queries, rj, gs, active)
 
 
 def greedy_lane_done(gs: GreedyState, rounds: int):
@@ -576,7 +581,9 @@ def _rerank_fused(points: QuantizedCorpus, queries, r: jnp.ndarray,
     host sync to compact through; the compacted QPS path reranks only the
     ambiguous (lane, slot) pairs — see ``_rerank_host``)."""
     fn = lambda q_, r_, i_, d_: _rerank_lane(points, q_, r_, i_, d_, metric)
-    ids, dists, count, n_amb = jax.vmap(fn)(queries, r, res.ids, res.dists)
+    with jax.named_scope("range.rerank"):
+        ids, dists, count, n_amb = jax.vmap(fn)(queries, r, res.ids,
+                                                res.dists)
     return dataclasses.replace(
         res, ids=ids, dists=dists, count=count,
         n_dist=res.n_dist + n_amb, n_rerank=res.n_rerank + n_amb)
@@ -601,14 +608,16 @@ def range_phase1(
     scheduler can admit new lanes mid-flight without re-running phase 1 for
     the whole device batch."""
     rj = broadcast_radius(r, queries.shape[0])
-    st = beam_search_batch(corpus, graph, queries, start_ids, rj, cfg.search,
-                           es_radius)
-    ids, dists, count, over = jax.vmap(
-        lambda st_, r_: _beam_results(st_, r_, cfg.result_cap))(st, rj)
-    if cfg.mode == "greedy":
-        need = jax.vmap(lambda st_, r_: _needs_phase2(st_, r_, cfg.lam))(st, rj)
-    else:
-        need = jnp.zeros_like(st.done)
+    with jax.named_scope("range.phase1"):
+        st = beam_search_batch(corpus, graph, queries, start_ids, rj,
+                               cfg.search, es_radius)
+        ids, dists, count, over = jax.vmap(
+            lambda st_, r_: _beam_results(st_, r_, cfg.result_cap))(st, rj)
+        if cfg.mode == "greedy":
+            need = jax.vmap(
+                lambda st_, r_: _needs_phase2(st_, r_, cfg.lam))(st, rj)
+        else:
+            need = jnp.zeros_like(st.done)
     res = RangeResult(ids=ids, dists=dists, count=count, overflow=over,
                       n_visited=st.n_visited, n_dist=st.n_dist,
                       es_stopped=st.es_stopped, phase2=jnp.zeros_like(st.done),
@@ -713,6 +722,7 @@ def _exact_pairs_for(points, queries, ids_p, lanes_p, metric: str,
     return _exact_pairs(raw, queries, ids_p, lanes_p, metric)
 
 
+@partial(jax.profiler.annotate_function, name="range.rerank")
 def _maybe_rerank_host(points, queries, rj: jnp.ndarray,
                        res: RangeResult, cfg: RangeConfig) -> RangeResult:
     """Host-compacted boundary rerank for the QPS path.
@@ -773,9 +783,10 @@ def _maybe_rerank_host(points, queries, rj: jnp.ndarray,
 @partial(jax.jit, static_argnames=("metric",))
 def _exact_pairs(raw, queries, ids_p, lanes_p, metric: str):
     """Exact f32 distances for flat (corpus id, query lane) pairs."""
-    vecs = jnp.take(raw, ids_p, axis=0).astype(jnp.float32)
-    qv = jnp.take(queries, lanes_p, axis=0).astype(jnp.float32)
-    return point_dist(vecs, qv, metric)
+    with jax.named_scope("range.rerank"):
+        vecs = jnp.take(raw, ids_p, axis=0).astype(jnp.float32)
+        qv = jnp.take(queries, lanes_p, axis=0).astype(jnp.float32)
+        return point_dist(vecs, qv, metric)
 
 
 def _walk_compacted(
@@ -816,66 +827,84 @@ def _walk_compacted(
     p1_search = cfg.search if cfg.mode != "doubling" else dataclasses.replace(
         cfg.search, max_beam=cfg.search.beam,
         visit_cap=min(cfg.search.visit_cap, 4 * cfg.search.beam))
-    st = beam_search_batch(points, graph, queries, start_ids, rj, p1_search, esj)
-    b_ids, b_dists, b_count, b_over = jax.vmap(
-        lambda st_, r_: _beam_results(st_, r_, cfg.result_cap))(st, rj)
-    base = RangeResult(ids=b_ids, dists=b_dists, count=b_count, overflow=b_over,
-                       n_visited=st.n_visited, n_dist=st.n_dist,
-                       es_stopped=st.es_stopped,
-                       phase2=jnp.zeros_like(st.done),
-                       n_rerank=jnp.zeros_like(st.n_visited))
-    if cfg.mode == "beam":
-        return finish(base)
-
-    active = np.asarray(jax.vmap(lambda st_, r_: _needs_phase2(st_, r_, cfg.lam))(st, rj))
-    n_active = int(active.sum())
+    with jax.profiler.TraceAnnotation("range.phase1"):
+        st = beam_search_batch(points, graph, queries, start_ids, rj,
+                               p1_search, esj)
+    greedy = cfg.mode == "greedy"
+    with jax.profiler.TraceAnnotation("range.compact") as span:
+        b_ids, b_dists, b_count, b_over = jax.vmap(
+            lambda st_, r_: _beam_results(st_, r_, cfg.result_cap))(st, rj)
+        base = RangeResult(
+            ids=b_ids, dists=b_dists, count=b_count, overflow=b_over,
+            n_visited=st.n_visited, n_dist=st.n_dist,
+            es_stopped=st.es_stopped, phase2=jnp.zeros_like(st.done),
+            n_rerank=jnp.zeros_like(st.n_visited),
+            p2_rounds=(np.full(queries.shape[0], -1, np.int32) if greedy
+                       else None))
+        n_active = bucket = 0
+        if cfg.mode != "beam":
+            active = np.asarray(jax.vmap(
+                lambda st_, r_: _needs_phase2(st_, r_, cfg.lam))(st, rj))
+            n_active = int(active.sum())
+        if n_active:
+            sel = np.nonzero(active)[0]
+            bucket = next_pow2(n_active)
+            pad = np.concatenate(
+                [sel, np.full(bucket - n_active, sel[0], dtype=sel.dtype)])
+            sub_q = queries[pad]
+            sub_r = rj[pad]
+            sub_es = None if esj is None else esj[pad]
+            if greedy:
+                sub_st = jax.tree.map(lambda x: x[pad], st)
+                lane_on = jnp.asarray(np.arange(bucket) < n_active)
+            else:  # per-lane starts subset with their lanes
+                sub_starts = start_ids if start_ids.ndim == 1 else start_ids[pad]
+        span.set_metadata(active=n_active, bucket=bucket)
     if n_active == 0:
         return finish(base)
 
-    sel = np.nonzero(active)[0]
-    bucket = next_pow2(n_active)
-    pad = np.concatenate([sel, np.full(bucket - n_active, sel[0], dtype=sel.dtype)])
-    sub_q = queries[pad]
-    sub_r = rj[pad]
-    sub_es = None if esj is None else esj[pad]
-    lane_on = jnp.asarray(np.arange(bucket) < n_active)
+    with jax.profiler.TraceAnnotation("range.phase2"):
+        if greedy:
+            gfn = lambda q_, r_, st_, a_: greedy_search(
+                points, graph, q_, r_, st_, cfg.result_cap,
+                cfg.frontier_rounds, cfg.search, a_)
+            gs = jax.vmap(gfn)(sub_q, sub_r, sub_st, lane_on)
+            sub = (gs.res_ids, gs.res_dists, gs.res_count, gs.overflow,
+                   gs.n_dist, gs.rounds)
+        else:
+            # restart with widening enabled, survivors only (paper Alg. 5),
+            # each at its own radius
+            st2 = beam_search_batch(points, graph, sub_q, sub_starts, sub_r,
+                                    cfg.search, sub_es)
+            d_ids, d_dists, d_count, d_over = jax.vmap(
+                lambda st_, r_: _beam_results(st_, r_, cfg.result_cap))(
+                    st2, sub_r)
+            sub = (d_ids, d_dists, d_count, d_over, st2.n_dist, None)
 
-    if cfg.mode == "doubling":
-        # restart with widening enabled, survivors only (paper Alg. 5),
-        # each at its own radius (per-lane starts subset with their lanes)
-        sub_starts = start_ids if start_ids.ndim == 1 else start_ids[pad]
-        st2 = beam_search_batch(points, graph, sub_q, sub_starts, sub_r,
-                                cfg.search, sub_es)
-        d_ids, d_dists, d_count, d_over = jax.vmap(
-            lambda st_, r_: _beam_results(st_, r_, cfg.result_cap))(st2, sub_r)
-        sub = (d_ids, d_dists, d_count, d_over, st2.n_dist)
-    else:
-        sub_st = jax.tree.map(lambda x: x[pad], st)
-        gfn = lambda q_, r_, st_, a_: greedy_search(
-            points, graph, q_, r_, st_, cfg.result_cap, cfg.frontier_rounds,
-            cfg.search, a_)
-        gs = jax.vmap(gfn)(sub_q, sub_r, sub_st, lane_on)
-        sub = (gs.res_ids, gs.res_dists, gs.res_count, gs.overflow, gs.n_dist)
-
-    # one batched transfer for everything the host-side merge needs (the
-    # per-leaf np.array() calls each synced the device separately)
-    ids, dists, count, over, ndist, s_ids, s_dists, s_count, s_over, s_nd = (
-        jax.device_get((base.ids, base.dists, base.count, base.overflow,
-                        base.n_dist) + sub))
-    ids, dists, count, over, ndist = (
-        np.array(ids), np.array(dists), np.array(count), np.array(over),
-        np.array(ndist))  # device_get leaves may be read-only views
-    ids[sel] = s_ids[:n_active]
-    dists[sel] = s_dists[:n_active]
-    count[sel] = s_count[:n_active]
-    over[sel] = s_over[:n_active]
-    ndist[sel] += s_nd[:n_active]
-    phase2 = jnp.asarray(active)
-    merged = RangeResult(ids=jnp.asarray(ids), dists=jnp.asarray(dists),
-                         count=jnp.asarray(count), overflow=jnp.asarray(over),
-                         n_visited=base.n_visited, n_dist=jnp.asarray(ndist),
-                         es_stopped=base.es_stopped, phase2=phase2,
-                         n_rerank=jnp.zeros_like(base.n_visited))
+    with jax.profiler.TraceAnnotation("range.merge"):
+        # one batched transfer for everything the host-side merge needs (the
+        # per-leaf np.array() calls each synced the device separately)
+        (ids, dists, count, over, ndist, s_ids, s_dists, s_count, s_over,
+         s_nd, s_rounds) = jax.device_get(
+            (base.ids, base.dists, base.count, base.overflow,
+             base.n_dist) + sub)
+        ids, dists, count, over, ndist = (
+            np.array(ids), np.array(dists), np.array(count), np.array(over),
+            np.array(ndist))  # device_get leaves may be read-only views
+        ids[sel] = s_ids[:n_active]
+        dists[sel] = s_dists[:n_active]
+        count[sel] = s_count[:n_active]
+        over[sel] = s_over[:n_active]
+        ndist[sel] += s_nd[:n_active]
+        p2_rounds = base.p2_rounds
+        if greedy:
+            p2_rounds[sel] = s_rounds[:n_active]
+        merged = RangeResult(
+            ids=jnp.asarray(ids), dists=jnp.asarray(dists),
+            count=jnp.asarray(count), overflow=jnp.asarray(over),
+            n_visited=base.n_visited, n_dist=jnp.asarray(ndist),
+            es_stopped=base.es_stopped, phase2=jnp.asarray(active),
+            n_rerank=jnp.zeros_like(base.n_visited), p2_rounds=p2_rounds)
     return finish(merged)
 
 

@@ -57,6 +57,7 @@ import warnings
 from collections import deque
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -325,6 +326,14 @@ class RangeServer:
             # aggregate-only workload: op="count" requests served (certified
             # per-lane match counts, no ids/dists payload)
             "count_requests": 0,
+            # work per request (lockstep path): distance computations and
+            # phase-1 expansions summed over served lanes; and greedy
+            # phase-2 straggler waste — expansion rounds of the request-
+            # carrying phase-2 lanes against dispatched lanes (pow2 padding
+            # included) x the slowest lane's rounds; their ratio is the
+            # phase-2 lane utilization
+            "n_dist": 0, "n_visited": 0,
+            "p2_lane_rounds": 0, "p2_slot_rounds": 0,
         }
 
     # -- served view ---------------------------------------------------------
@@ -632,95 +641,127 @@ class RangeServer:
         """
         if self._pool is not None:
             return self._step_continuous()
+        # host spans (no-ops unless a profiler trace is active): range.step
+        # and, inside it and in order, range.batch, the walk's range.phase1 /
+        # compact / phase2 / merge / rerank, then range.respond
+        with jax.profiler.TraceAnnotation(
+                "range.step", batch=self.stats["batches"]) as span:
+            return self._step_lockstep(span)
+
+    def _step_lockstep(self, span) -> list[Response]:
         if self.fleet is not None:
             # background recovery sweep: rebuild lost replicas and re-admit
             # them through the breaker's half-open probe
             self.fleet.maintain()
             self.stats.update(self.fleet.stats)
-        batch = self._drain()
-        if not batch:
-            return []
-        svc0 = self._clock()
-        out = []
-        if self.live is not None:
-            muts = [b for b in batch if b[0].op in ("insert", "delete")]
-            batch = [b for b in batch if b[0].op in ("range", "count")]
-            if muts:
-                out.extend(self._apply_mutations(muts, svc0))
-                if (self.scfg.auto_consolidate
-                        and self.live.maybe_consolidate()):
-                    self.stats["consolidations"] += 1
-                self._view = self.live.snapshot()
-            self.stats["epoch"] = self._view.epoch
-            self.stats["batches"] += 1 if (muts and not batch) else 0
-        batch, shed = self._shed_expired(batch, svc0)
-        out.extend(shed)
-        if not batch:
-            return out
-        reqs = [b[0] for b in batch]
-        arrive = [b[1] for b in batch]
-        n = len(reqs)
-        bucket = next_pow2(n)
-        q = np.stack([rq.query for rq in reqs])
-        radii = np.asarray(
-            [self.scfg.default_radius if rq.radius is None else rq.radius
-             for rq in reqs], np.float32)
-        if bucket > n:  # pad to bucket with repeats (masked out of responses)
-            q = np.concatenate([q, np.repeat(q[:1], bucket - n, axis=0)])
-            radii = np.concatenate([radii, np.repeat(radii[:1], bucket - n)])
-        lf = self._batch_filter(reqs, bucket)
-        n_filtered = sum(rq.filter_labels is not None for rq in reqs)
+        with jax.profiler.TraceAnnotation("range.batch"):
+            batch = self._drain()
+            if not batch:
+                return []
+            svc0 = self._clock()
+            out = []
+            if self.live is not None:
+                muts = [b for b in batch if b[0].op in ("insert", "delete")]
+                batch = [b for b in batch if b[0].op in ("range", "count")]
+                if muts:
+                    out.extend(self._apply_mutations(muts, svc0))
+                    if (self.scfg.auto_consolidate
+                            and self.live.maybe_consolidate()):
+                        self.stats["consolidations"] += 1
+                    self._view = self.live.snapshot()
+                self.stats["epoch"] = self._view.epoch
+                self.stats["batches"] += 1 if (muts and not batch) else 0
+            batch, shed = self._shed_expired(batch, svc0)
+            out.extend(shed)
+            if not batch:
+                return out
+            reqs = [b[0] for b in batch]
+            arrive = [b[1] for b in batch]
+            n = len(reqs)
+            bucket = next_pow2(n)
+            span.set_metadata(n=n, bucket=bucket)
+            q = np.stack([rq.query for rq in reqs])
+            radii = np.asarray(
+                [self.scfg.default_radius if rq.radius is None else rq.radius
+                 for rq in reqs], np.float32)
+            if bucket > n:  # pad with repeats (masked out of responses)
+                q = np.concatenate([q, np.repeat(q[:1], bucket - n, axis=0)])
+                radii = np.concatenate(
+                    [radii, np.repeat(radii[:1], bucket - n)])
+            lf = self._batch_filter(reqs, bucket)
+            n_filtered = sum(rq.filter_labels is not None for rq in reqs)
         res, degraded = self._execute(q, radii, lf)
-        now = self._clock()
-        ids = np.asarray(res.ids)
-        dists = np.asarray(res.dists)
-        counts = np.asarray(res.count)
-        over = np.asarray(res.overflow)
-        ess = np.asarray(res.es_stopped)
-        epoch = self._epoch()
-        dkw = {}
-        if degraded is not None:  # annotate shard health on every response
-            dkw = dict(shards_ok=degraded.shards_ok,
-                       shards_total=degraded.shards_total,
-                       complete=degraded.complete,
-                       coverage=degraded.coverage,
-                       code=degraded.code)
-            if hasattr(degraded, "replica_ok"):  # replicated fan-out
-                dkw.update(replicas_ok=degraded.replicas_ok,
-                           replicas_total=degraded.replicas_total)
-        for i, rq in enumerate(reqs):
-            row = ids[i]
-            valid = row != INVALID_ID
-            if rq.op == "count":  # certified count only, no payload
-                r_ids = np.zeros(0, row.dtype)
-                r_dists = np.zeros(0, np.float32)
-            else:
-                r_ids, r_dists = row[valid], dists[i][valid]
-            out.append(self._record(Response(
-                req_id=rq.req_id,
-                op=rq.op,
-                ids=r_ids,
-                dists=r_dists,
-                count=int(counts[i]),
-                overflow=bool(over[i]),
-                es_stopped=bool(ess[i]),
-                latency_s=now - arrive[i],
-                radius=float(radii[i]),
-                epoch=epoch,
-                timings=self._timings(arrive[i], svc0, now),
-                filtered=rq.filter_labels is not None,
-                **dkw,
-            )))
-        self.stats["served"] += n
-        self.stats["count_requests"] += sum(rq.op == "count" for rq in reqs)
-        self.stats["batches"] += 1
-        self.stats["filtered_batches"] += int(lf is not None)
-        self.stats["filtered_requests"] += n_filtered
-        self.stats["es_stopped"] += int(ess[:n].sum())
-        self.stats["overflow"] += int(over[:n].sum())
-        self.stats["reranked"] += int(np.asarray(res.n_rerank)[:n].sum())
-        self._track_radii(radii[:n])
+        with jax.profiler.TraceAnnotation("range.respond") as rspan:
+            now = self._clock()
+            # one batched fetch of everything the responses and counters read
+            ids, dists, counts, over, ess, nrr, ndist, nvis, p2 = (
+                jax.device_get((res.ids, res.dists, res.count, res.overflow,
+                                res.es_stopped, res.n_rerank, res.n_dist,
+                                res.n_visited, res.p2_rounds)))
+            epoch = self._epoch()
+            dkw = {}
+            if degraded is not None:  # annotate shard health on every response
+                dkw = dict(shards_ok=degraded.shards_ok,
+                           shards_total=degraded.shards_total,
+                           complete=degraded.complete,
+                           coverage=degraded.coverage,
+                           code=degraded.code)
+                if hasattr(degraded, "replica_ok"):  # replicated fan-out
+                    dkw.update(replicas_ok=degraded.replicas_ok,
+                               replicas_total=degraded.replicas_total)
+            for i, rq in enumerate(reqs):
+                row = ids[i]
+                valid = row != INVALID_ID
+                if rq.op == "count":  # certified count only, no payload
+                    r_ids = np.zeros(0, row.dtype)
+                    r_dists = np.zeros(0, np.float32)
+                else:
+                    r_ids, r_dists = row[valid], dists[i][valid]
+                out.append(self._record(Response(
+                    req_id=rq.req_id,
+                    op=rq.op,
+                    ids=r_ids,
+                    dists=r_dists,
+                    count=int(counts[i]),
+                    overflow=bool(over[i]),
+                    es_stopped=bool(ess[i]),
+                    latency_s=now - arrive[i],
+                    radius=float(radii[i]),
+                    epoch=epoch,
+                    timings=self._timings(arrive[i], svc0, now),
+                    filtered=rq.filter_labels is not None,
+                    **dkw,
+                )))
+            work = self._count_work(n, ndist, nvis, p2)
+            rspan.set_metadata(**work)
+            self.stats["served"] += n
+            self.stats["count_requests"] += sum(rq.op == "count"
+                                                for rq in reqs)
+            self.stats["batches"] += 1
+            self.stats["filtered_batches"] += int(lf is not None)
+            self.stats["filtered_requests"] += n_filtered
+            self.stats["es_stopped"] += int(ess[:n].sum())
+            self.stats["overflow"] += int(over[:n].sum())
+            self.stats["reranked"] += int(nrr[:n].sum())
+            self._track_radii(radii[:n])
         return out
+
+    def _count_work(self, n: int, ndist, nvis, p2) -> dict:
+        """Add one batch's work to the counters; returns the increments.
+        ``p2`` is the batch's ``RangeResult.p2_rounds`` (None adds nothing
+        to the phase-2 counters)."""
+        work = {"n_dist": int(ndist[:n].sum()),
+                "n_visited": int(nvis[:n].sum()),
+                "p2_lane_rounds": 0, "p2_slot_rounds": 0}
+        if p2 is not None:
+            on = p2 >= 0
+            if on.any():
+                work["p2_lane_rounds"] = int(p2[:n][on[:n]].sum())
+                work["p2_slot_rounds"] = (next_pow2(int(on.sum()))
+                                          * int(p2.max()))
+        for k, v in work.items():
+            self.stats[k] += v
+        return work
 
     # -- continuous execution ------------------------------------------------
     def _step_continuous(self) -> list[Response]:
