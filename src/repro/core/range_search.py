@@ -98,6 +98,11 @@ class RangeResult:
     # (Q,) int32 host array — greedy phase-2 expansions per lane, -1 where
     # the lane stopped at phase 1; None on paths that do not track them
     p2_rounds: Optional[np.ndarray] = None
+    # host ints of the sliced greedy phase 2 (same paths as p2_rounds):
+    # lane-rounds dispatched (over its slices, bucket x the slowest lane's
+    # advance) and the number of slices
+    p2_slot_rounds: Optional[int] = None
+    p2_slices: Optional[int] = None
 
 
 # ---------------------------------------------------------------------------
@@ -410,9 +415,13 @@ def greedy_lane_done(gs: GreedyState, rounds: int):
     same flags as the one-shot path."""
     ptr = np.asarray(gs.expand_ptr)
     cnt = np.asarray(gs.res_count)
-    rds = np.asarray(gs.rounds)
-    done = (ptr >= cnt) | (rds >= rounds)
+    done = _lane_done(ptr, cnt, np.asarray(gs.rounds), rounds)
     return done, np.asarray(gs.overflow) | (done & (ptr < cnt))
+
+
+def _lane_done(ptr, cnt, rds, rounds: int) -> np.ndarray:
+    """Frontier exhausted or lifetime expansion budget spent."""
+    return (ptr >= cnt) | (rds >= rounds)
 
 
 def greedy_coverage(gs: GreedyState) -> np.ndarray:
@@ -789,6 +798,89 @@ def _exact_pairs(raw, queries, ids_p, lanes_p, metric: str):
         return point_dist(vecs, qv, metric)
 
 
+# Lane expansions at which the compacted path's greedy phase 2 pauses its
+# lockstep walk and re-packs the still-live lanes into the smallest pow2
+# bucket that holds them, so finished lanes stop riding the straggler's
+# loop (a vmapped while_loop runs its body on every lane of the bucket).
+# Past the last end, or once the bucket is one lane, the rest runs to
+# completion in one call. Per-lane rounds are heavy-tailed: most lanes stop
+# within a few hundred expansions, one per batch may run the whole budget;
+# ends that double from 128 served bigann-int8 faster on a v5e than ends
+# every 256 expansions or ends doubling from 256.
+P2_SLICE_ENDS = (128, 256, 512, 1024)
+
+
+@jax.jit
+def _retire_lanes(out, gs: GreedyState, rows, keep, carry):
+    """One program per phase-2 slice boundary: write every lane of the
+    bucket, with ``greedy_search``'s end-of-run overflow bit, into the
+    output (a row per lane of the first bucket) at ``rows`` (out of range:
+    dropped; ``out=None`` starts the output from the first bucket), then
+    gather lanes ``keep`` of ``(gs, carry)`` into the next bucket
+    (``keep=None`` on the last call). A lane still live is written again
+    later, so each row ends with its lane's final state."""
+    fin = (gs.res_ids, gs.res_dists, gs.res_count,
+           gs.overflow | (gs.expand_ptr < gs.res_count), gs.n_dist,
+           gs.rounds)
+    if out is not None:
+        fin = tuple(o.at[rows].set(f, mode="drop") for o, f in zip(out, fin))
+    if keep is None:
+        return fin, None
+    return fin, jax.tree.map(lambda x: x[keep], (gs, carry))
+
+
+def _greedy_sliced(points, graph: Graph, qs, rs, st: BeamState,
+                   n_active: int, cfg: RangeConfig):
+    """Greedy phase 2 over a pow2 bucket of compacted lanes (the first
+    ``n_active`` carry requests, the rest are inactive padding), run in
+    slices that end at ``P2_SLICE_ENDS``.
+
+    After each slice one fetch of the per-lane ``(expand_ptr, res_count,
+    rounds)`` tells which lanes are done; where the live ones fit a smaller
+    pow2 bucket, ``_retire_lanes`` moves them there. The carry is the whole
+    lane checkpoint (``greedy_resume_batch``), so the answers equal one
+    vmapped ``greedy_search`` over the bucket, bit for bit.
+
+    Returns the device-resident ``(ids, dists, count, overflow, n_dist,
+    rounds)`` of the bucket's rows, the lane-rounds dispatched (over the
+    slices, bucket x the slowest lane's advance) and each slice's bucket.
+    """
+    cap, budget, scfg = cfg.result_cap, cfg.frontier_rounds, cfg.search
+    width = qs.shape[0]
+    gs = greedy_seed_batch(points, st, rs, cap, scfg)
+    rows = np.where(np.arange(width) < n_active, np.arange(width),
+                    width).astype(np.int32)
+    on = rows < width
+    before = np.zeros(width, np.int32)
+    out, slot_rounds, buckets, start = None, 0, [], 0
+    for end in P2_SLICE_ENDS + (budget,):
+        b = len(rows)
+        to_end = b == 1 or end >= budget
+        gs = greedy_resume_batch(points, graph, qs, rs, gs, jnp.asarray(on),
+                                 cap, budget, budget if to_end else end - start,
+                                 scfg)
+        ptr, cnt, rds = jax.device_get((gs.expand_ptr, gs.res_count,
+                                        gs.rounds))
+        slot_rounds += b * int((rds - before).max())
+        buckets.append(b)
+        live = on & ~_lane_done(ptr, cnt, rds, budget)
+        n_live = int(live.sum())
+        if to_end or not n_live:
+            break
+        start, before = end, rds
+        if next_pow2(n_live) == b:  # cannot shrink: go on in place
+            continue
+        keep = np.nonzero(live)[0].astype(np.int32)
+        keep = np.concatenate(
+            [keep, np.full(next_pow2(n_live) - n_live, keep[0], np.int32)])
+        out, (gs, (qs, rs)) = _retire_lanes(out, gs, rows, keep, (qs, rs))
+        on = np.arange(len(keep)) < n_live
+        rows = np.where(on, rows[keep], width).astype(np.int32)
+        before = rds[keep]
+    out, _ = _retire_lanes(out, gs, rows, None, None)
+    return out, slot_rounds, buckets
+
+
 def _walk_compacted(
     corpus,               # (N, d) array or QuantizedCorpus
     graph: Graph,
@@ -840,7 +932,9 @@ def _walk_compacted(
             es_stopped=st.es_stopped, phase2=jnp.zeros_like(st.done),
             n_rerank=jnp.zeros_like(st.n_visited),
             p2_rounds=(np.full(queries.shape[0], -1, np.int32) if greedy
-                       else None))
+                       else None),
+            p2_slot_rounds=0 if greedy else None,
+            p2_slices=0 if greedy else None)
         n_active = bucket = 0
         if cfg.mode != "beam":
             active = np.asarray(jax.vmap(
@@ -856,21 +950,19 @@ def _walk_compacted(
             sub_es = None if esj is None else esj[pad]
             if greedy:
                 sub_st = jax.tree.map(lambda x: x[pad], st)
-                lane_on = jnp.asarray(np.arange(bucket) < n_active)
             else:  # per-lane starts subset with their lanes
                 sub_starts = start_ids if start_ids.ndim == 1 else start_ids[pad]
         span.set_metadata(active=n_active, bucket=bucket)
     if n_active == 0:
         return finish(base)
 
-    with jax.profiler.TraceAnnotation("range.phase2"):
+    with jax.profiler.TraceAnnotation("range.phase2") as span:
         if greedy:
-            gfn = lambda q_, r_, st_, a_: greedy_search(
-                points, graph, q_, r_, st_, cfg.result_cap,
-                cfg.frontier_rounds, cfg.search, a_)
-            gs = jax.vmap(gfn)(sub_q, sub_r, sub_st, lane_on)
-            sub = (gs.res_ids, gs.res_dists, gs.res_count, gs.overflow,
-                   gs.n_dist, gs.rounds)
+            sub, slot_rounds, buckets = _greedy_sliced(
+                points, graph, sub_q, sub_r, sub_st, n_active, cfg)
+            # "64-16-8": a comma would split the trace event's arguments
+            span.set_metadata(slices=len(buckets),
+                              buckets="-".join(map(str, buckets)))
         else:
             # restart with widening enabled, survivors only (paper Alg. 5),
             # each at its own radius
@@ -896,15 +988,17 @@ def _walk_compacted(
         count[sel] = s_count[:n_active]
         over[sel] = s_over[:n_active]
         ndist[sel] += s_nd[:n_active]
-        p2_rounds = base.p2_rounds
+        p2 = {}
         if greedy:
-            p2_rounds[sel] = s_rounds[:n_active]
+            base.p2_rounds[sel] = s_rounds[:n_active]
+            p2 = dict(p2_rounds=base.p2_rounds, p2_slot_rounds=slot_rounds,
+                      p2_slices=len(buckets))
         merged = RangeResult(
             ids=jnp.asarray(ids), dists=jnp.asarray(dists),
             count=jnp.asarray(count), overflow=jnp.asarray(over),
             n_visited=base.n_visited, n_dist=jnp.asarray(ndist),
             es_stopped=base.es_stopped, phase2=jnp.asarray(active),
-            n_rerank=jnp.zeros_like(base.n_visited), p2_rounds=p2_rounds)
+            n_rerank=jnp.zeros_like(base.n_visited), **p2)
     return finish(merged)
 
 

@@ -329,11 +329,12 @@ class RangeServer:
             # work per request (lockstep path): distance computations and
             # phase-1 expansions summed over served lanes; and greedy
             # phase-2 straggler waste — expansion rounds of the request-
-            # carrying phase-2 lanes against dispatched lanes (pow2 padding
-            # included) x the slowest lane's rounds; their ratio is the
-            # phase-2 lane utilization
+            # carrying phase-2 lanes against the lane-rounds dispatched
+            # (per slice, bucket incl. pow2 padding x the slowest lane's
+            # advance); their ratio is the phase-2 lane utilization, and
+            # p2_slices counts the phase-2 dispatches
             "n_dist": 0, "n_visited": 0,
-            "p2_lane_rounds": 0, "p2_slot_rounds": 0,
+            "p2_lane_rounds": 0, "p2_slot_rounds": 0, "p2_slices": 0,
         }
 
     # -- served view ---------------------------------------------------------
@@ -694,10 +695,11 @@ class RangeServer:
         with jax.profiler.TraceAnnotation("range.respond") as rspan:
             now = self._clock()
             # one batched fetch of everything the responses and counters read
-            ids, dists, counts, over, ess, nrr, ndist, nvis, p2 = (
-                jax.device_get((res.ids, res.dists, res.count, res.overflow,
-                                res.es_stopped, res.n_rerank, res.n_dist,
-                                res.n_visited, res.p2_rounds)))
+            (ids, dists, counts, over, ess, nrr, ndist, nvis, p2,
+             p2_slots, p2_slices) = jax.device_get(
+                 (res.ids, res.dists, res.count, res.overflow,
+                  res.es_stopped, res.n_rerank, res.n_dist, res.n_visited,
+                  res.p2_rounds, res.p2_slot_rounds, res.p2_slices))
             epoch = self._epoch()
             dkw = {}
             if degraded is not None:  # annotate shard health on every response
@@ -732,7 +734,8 @@ class RangeServer:
                     filtered=rq.filter_labels is not None,
                     **dkw,
                 )))
-            work = self._count_work(n, ndist, nvis, p2)
+            work = self._count_work(n, ndist, nvis, p2, p2_slots,
+                                    p2_slices)
             rspan.set_metadata(**work)
             self.stats["served"] += n
             self.stats["count_requests"] += sum(rq.op == "count"
@@ -746,19 +749,18 @@ class RangeServer:
             self._track_radii(radii[:n])
         return out
 
-    def _count_work(self, n: int, ndist, nvis, p2) -> dict:
+    def _count_work(self, n: int, ndist, nvis, p2, p2_slots,
+                    p2_slices) -> dict:
         """Add one batch's work to the counters; returns the increments.
-        ``p2`` is the batch's ``RangeResult.p2_rounds`` (None adds nothing
-        to the phase-2 counters)."""
+        ``p2``, ``p2_slots`` and ``p2_slices`` are the batch's
+        ``RangeResult.p2_rounds`` / ``p2_slot_rounds`` / ``p2_slices``
+        (None adds nothing to the phase-2 counters)."""
         work = {"n_dist": int(ndist[:n].sum()),
                 "n_visited": int(nvis[:n].sum()),
-                "p2_lane_rounds": 0, "p2_slot_rounds": 0}
-        if p2 is not None:
-            on = p2 >= 0
-            if on.any():
-                work["p2_lane_rounds"] = int(p2[:n][on[:n]].sum())
-                work["p2_slot_rounds"] = (next_pow2(int(on.sum()))
-                                          * int(p2.max()))
+                "p2_lane_rounds": 0 if p2 is None
+                else int(p2[:n][p2[:n] >= 0].sum()),
+                "p2_slot_rounds": int(p2_slots or 0),
+                "p2_slices": int(p2_slices or 0)}
         for k, v in work.items():
             self.stats[k] += v
         return work

@@ -19,7 +19,7 @@ from repro.core.beam_search import beam_search_batch
 from repro.core.corpus import QuantizedCorpus
 from repro.core.graph import Graph
 from repro.core.range_search import (
-    greedy_resume_batch, greedy_seed_batch, range_phase1,
+    _retire_lanes, greedy_resume_batch, greedy_seed_batch, range_phase1,
 )
 from repro.kernels.expand import expand_frontier
 from repro.launch.serve import serving_range_cfg
@@ -88,6 +88,18 @@ def test_range_phase1_compiles(one_chip, use_kernel):
     assert _has_kernel(compiled) == use_kernel
 
 
+def _seed_state(one_chip, cfg, corpus, graph, qs, radii):
+    """The greedy seed state's shapes, from phase 1 traced abstractly."""
+    st = jax.eval_shape(
+        lambda c, g, q, s, r: beam_search_batch(c, g, q, s, r, cfg.search),
+        corpus, graph, qs, _spec(one_chip, (4,), jnp.int32), radii)
+    gs = jax.eval_shape(
+        lambda c, st_, r: greedy_seed_batch(c, st_, r, cap=cfg.result_cap,
+                                            scfg=cfg.search),
+        corpus, st, radii)
+    return jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype), gs)
+
+
 @pytest.mark.parametrize("use_kernel", [False, True])
 def test_greedy_resume_compiles(one_chip, use_kernel):
     cfg = serving_range_cfg("l2", expand_width=E, corpus_dtype="int8",
@@ -96,17 +108,25 @@ def test_greedy_resume_compiles(one_chip, use_kernel):
     graph = Graph(neighbors=_spec(one_chip, (N, 32), jnp.int32))
     qs = _spec(one_chip, (Q, D), jnp.float32)
     radii = _spec(one_chip, (Q,), jnp.float32)
-    # the greedy seed state's shapes, from phase 1 traced abstractly
-    st = jax.eval_shape(
-        lambda c, g, q, s, r: beam_search_batch(c, g, q, s, r, cfg.search),
-        corpus, graph, qs, _spec(one_chip, (4,), jnp.int32), radii)
-    gs = jax.eval_shape(
-        lambda c, st_, r: greedy_seed_batch(c, st_, r, cap=cfg.result_cap,
-                                            scfg=cfg.search),
-        corpus, st, radii)
-    gs = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype), gs)
+    gs = _seed_state(one_chip, cfg, corpus, graph, qs, radii)
     compiled = greedy_resume_batch.lower(
         corpus, graph, qs, radii, gs, _spec(one_chip, (Q,), jnp.bool_),
         cap=cfg.result_cap, rounds=cfg.frontier_rounds, slice_rounds=8,
         scfg=cfg.search).compile()
     assert _has_kernel(compiled) == use_kernel
+
+
+def test_retire_lanes_compiles(one_chip):
+    """The phase-2 slice boundary: a full 128-lane bucket written into the
+    batch-sized output, its live lanes gathered into a 32-lane bucket."""
+    cfg = serving_range_cfg("l2", expand_width=E, corpus_dtype="int8")
+    corpus = _corpus(one_chip, "int8")
+    graph = Graph(neighbors=_spec(one_chip, (N, 32), jnp.int32))
+    qs = _spec(one_chip, (Q, D), jnp.float32)
+    radii = _spec(one_chip, (Q,), jnp.float32)
+    gs = _seed_state(one_chip, cfg, corpus, graph, qs, radii)
+    out = (gs.res_ids, gs.res_dists, gs.res_count, gs.overflow, gs.n_dist,
+           gs.rounds)
+    rows = _spec(one_chip, (Q,), jnp.int32)
+    _retire_lanes.lower(out, gs, rows, _spec(one_chip, (Q // 4,), jnp.int32),
+                        (qs, radii)).compile()
