@@ -78,14 +78,12 @@ class CompileCounter:
 
 
 def range_cfg(cfg: dict, **extra):
+    """The configuration's whole ``search`` dict, its metric and storage,
+    with ``extra`` on top; ``overrides`` refuses an unknown key."""
     from repro.configs.range_engine import EngineDeployConfig
-    s = cfg["search"]
-    return EngineDeployConfig().overrides(
-        metric=cfg["profile"]["metric"], corpus_dtype=cfg["corpus_dtype"],
-        beam=s["beam"], max_beam=s["max_beam"], visit_cap=s["visit_cap"],
-        result_cap=s["result_cap"], mode=s["mode"],
-        frontier_rounds=s["frontier_rounds"], lam=s["lam"],
-        **extra).range_cfg
+    return EngineDeployConfig().overrides(**{
+        **cfg["search"], "metric": cfg["profile"]["metric"],
+        "corpus_dtype": cfg["corpus_dtype"], **extra}).range_cfg
 
 
 def warm_up(server, queries, radius, max_batch) -> int:
@@ -148,7 +146,8 @@ def check(cfg, points, queries, radius, log: traffic.Log,
     rids = list(log.ids)
     answered = [(log.pool_idx[rid], log.ids[rid]) for rid in rids]
     t = time.perf_counter()
-    cmp = reference.compare(points, queries, radius, answered)
+    cmp = reference.compare(points, queries, radius, answered,
+                            cfg["profile"]["metric"])
     cmp["reference_s"] = time.perf_counter() - t
     inw = [k for k, rid in enumerate(rids) if log.done[rid] <= t_close]
     size = sum(cmp["sizes"][k] for k in inw)
@@ -191,9 +190,7 @@ def serving(cfg, pts, graph, control: bool):
         pts, graph, metric=metric,
         corpus_dtype=None if dtype == "float32" else dtype)
     rcfg = range_cfg(dict(cfg, corpus_dtype=dtype), **extra)
-    sc = cfg["server"]
-    return eng, rcfg, RangeServer(eng, rcfg, ServerConfig(
-        max_batch=sc["max_batch"], max_queue=sc["max_queue"]))
+    return eng, rcfg, RangeServer(eng, rcfg, ServerConfig(**cfg["server"]))
 
 
 def run_cell(cell_name: str, seed: int, seconds: float, traced: bool, *,

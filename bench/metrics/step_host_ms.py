@@ -1,6 +1,5 @@
 """Device-idle time inside the server's range.step spans, in ms per step:
-the host path of a lockstep step seen from inside the program (the inside
-twin of host_ms_per_batch)."""
+the host path of a lockstep step seen from inside the program."""
 from bench import spans
 
 

@@ -20,35 +20,77 @@ def _cfg(name="bigann-int8"):
         return json.load(f)
 
 
-def test_exact_range_decides_the_boundary_exactly():
-    # 1 + 2^-12 rounds to 1.0 in bfloat16, so a bfloat16 distance puts the
-    # first point on the radius; its exact distance lies outside
+def _boundary_case(metric):
+    """Points on, just inside and just outside the radius of query 0; the
+    first point is the one bfloat16 misplaces."""
     pts = np.zeros((6, 4), np.float32)
-    pts[0, 0] = 1.0 + 2.0 ** -12
-    pts[1, 0] = 1.0 - 2.0 ** -12
-    pts[2, 0] = 1.0
-    pts[3, 1] = 3.0
-    pts[4, :] = 0.25
-    pts[5, 2] = -0.5
     q = np.zeros((2, 4), np.float32)
-    q[1, 1] = 3.0
-    got = reference.exact_range(pts, q, 1.0)
-    assert got[0].tolist() == [1, 2, 4, 5]
-    assert got[1].tolist() == [3]
+    if metric == "l2":
+        # 1 + 2^-12 rounds to 1.0 in bfloat16, so a bfloat16 distance puts
+        # the first point on the radius; its exact distance lies outside
+        pts[0, 0] = 1.0 + 2.0 ** -12
+        pts[1, 0] = 1.0 - 2.0 ** -12
+        pts[2, 0] = 1.0
+        pts[3, 1] = 3.0
+        pts[4, :] = 0.25
+        pts[5, 2] = -0.5
+        q[1, 1] = 3.0
+        return pts, q, 1.0, [[1, 2, 4, 5], [3]]
+    # -q.x <= -1: q.x at least 1. 1 - 2^-12 rounds to 1.0 in bfloat16, so
+    # a bfloat16 distance puts the first point on the radius; its exact
+    # distance lies outside. The third and fifth lie exactly on it.
+    q[0, 0] = 1.0
+    q[1, :] = [0.5, 0.25, 0.0, -2.0]
+    pts[0, 0] = 1.0 - 2.0 ** -12
+    pts[1, 0] = 1.0 + 2.0 ** -12
+    pts[2, 0] = 1.0
+    pts[3, :] = [1.0, 2.0, 0.0, 0.0]      # q1.x = 1 exactly
+    pts[4, :] = [1.0, 0.0, 7.0, -0.25]
+    pts[5, :] = [-4.0, 0.0, 0.0, -3.0]    # q1.x = 4
+    return pts, q, -1.0, [[1, 2, 3, 4], [3, 4, 5]]
+
+
+@pytest.mark.parametrize("metric", ["l2", "ip"])
+def test_exact_range_decides_the_boundary_exactly(metric):
+    pts, q, r, want = _boundary_case(metric)
+    got = reference.exact_range(pts, q, r, metric)
+    assert [g.tolist() for g in got] == want
     import jax.numpy as jnp
-    bf = jnp.asarray(pts, jnp.bfloat16).astype(jnp.float32)
-    assert float(jnp.sum(bf[0] ** 2)) <= 1.0   # what bfloat16 would keep
+    bf = np.asarray(jnp.asarray(pts, jnp.bfloat16).astype(jnp.float32),
+                    np.float64)
+    d_bf = reference.exact_dists(bf, q[0], np.array([0]), metric)[0]
+    assert d_bf <= r < reference.exact_dists(pts, q[0], np.array([0]),
+                                             metric)[0]
 
 
-def test_exact_range_matches_a_full_float64_scan():
+@pytest.mark.parametrize("metric,data,r", [
+    ("l2", "normal", 9.0), ("ip", "normal", -9.0),
+    ("l2", "grid", 40.0), ("ip", "grid", -20.0)])
+def test_exact_range_matches_a_full_float64_scan(metric, data, r):
     rng = np.random.default_rng(3)
-    pts = rng.standard_normal((3000, 16)).astype(np.float32)
-    qs = rng.standard_normal((40, 16)).astype(np.float32)
-    r = 9.0
-    got = reference.exact_range(pts, qs, r, block=7)
+    if data == "normal":
+        pts = rng.standard_normal((3000, 16)).astype(np.float32)
+        qs = rng.standard_normal((40, 16)).astype(np.float32)
+    else:
+        # small whole numbers: every distance is a whole number, so many
+        # points lie exactly on the radius
+        pts = rng.integers(-3, 4, (3000, 16)).astype(np.float32)
+        qs = rng.integers(-3, 4, (40, 16)).astype(np.float32)
+    got = reference.exact_range(pts, qs, r, metric, block=7)
+    on = 0
     for i, q in enumerate(qs):
-        d = ((pts.astype(np.float64) - q.astype(np.float64)) ** 2).sum(1)
+        x, qq = pts.astype(np.float64), q.astype(np.float64)
+        d = ((x - qq) ** 2).sum(1) if metric == "l2" else -(x @ qq)
         assert np.array_equal(np.nonzero(d <= r)[0], got[i])
+        on += int((d == r).sum())
+    assert sum(map(len, got)) > 0
+    assert (on > 0) == (data == "grid")
+
+
+def test_exact_range_refuses_an_unknown_metric():
+    pts = np.zeros((2, 2), np.float32)
+    with pytest.raises(ValueError, match="cosine"):
+        reference.exact_range(pts, pts, 1.0, "cosine")
 
 
 def test_average_precision_by_hand():
@@ -59,21 +101,39 @@ def test_average_precision_by_hand():
                                        [np.array([1])]) == 1.0
 
 
-def test_compare_flags_wrong_answers():
+@pytest.mark.parametrize("metric", ["l2", "ip"])
+def test_compare_flags_wrong_answers(metric):
+    # the second point lies inside, the third just outside, the fourth far
+    # outside; max_excess is the third's excess relative to |r|
     pts = np.zeros((4, 2), np.float32)
-    pts[1, 0] = 0.5
-    pts[2, 0] = 1.0 + 2.0 ** -10
-    pts[3, 0] = 3.0
     q = np.zeros((1, 2), np.float32)
-    ok = reference.compare(pts, q, 1.0, [(0, np.array([0, 1]))])
+    if metric == "l2":
+        r = 1.0
+        pts[1, 0] = 0.5
+        pts[2, 0] = 1.0 + 2.0 ** -10
+        pts[3, 0] = 3.0
+        excess = (1 + 2.0 ** -10) ** 2 - 1
+    else:
+        # -q.x <= -2; the third point's distance is -2 + 2^-9, so
+        # (d - r) / |r| = 2^-10, where (d - r) / r would read below 0
+        r = -2.0
+        q[0, 0] = 1.0
+        pts[0, 0] = 3.0
+        pts[1, 0] = 2.5
+        pts[2, 0] = 2.0 - 2.0 ** -9
+        pts[3, 0] = -3.0
+        excess = 2.0 ** -10
+    ok = reference.compare(pts, q, r, [(0, np.array([0, 1]))], metric)
     assert ok["ap"] == 1.0 and ok["max_excess"] == 0.0
     assert ok["bad_ids"] == 0 and ok["false_positives"] == 0
-    out = reference.compare(pts, q, 1.0, [(0, np.array([0, 2]))])
+    out = reference.compare(pts, q, r, [(0, np.array([0, 2]))], metric)
     assert out["false_positives"] == 1
-    assert out["max_excess"] == pytest.approx((1 + 2.0 ** -10) ** 2 - 1)
+    assert out["max_excess"] == pytest.approx(excess)
     assert out["ap"] == pytest.approx(0.5)
-    bad = reference.compare(pts, q, 1.0, [(0, np.array([0, 0, 1])),
-                                          (0, np.array([7]))])
+    far = reference.compare(pts, q, r, [(0, np.array([3]))], metric)
+    assert far["max_excess"] > 1.0
+    bad = reference.compare(pts, q, r, [(0, np.array([0, 0, 1])),
+                                        (0, np.array([7]))], metric)
     assert bad["bad_ids"] == 2
 
 
@@ -82,7 +142,7 @@ def test_frozen_generator_and_radius_are_pinned():
     digest = hashlib.sha256(p.tobytes() + q.tobytes()).hexdigest()
     assert digest == ("255174e7a2e3d0446fc785bcfdd1973e"
                       "4aeaeb303deb3e7452625ba2f1e4ab48")
-    sel = radius.select_radius(p, q)
+    sel = radius.select_radius(p, q, "l2")
     assert sel["grid_index"] == 0 and sel["max_matches"] == 33
     assert sel["radius"] == pytest.approx(0.018829556182026863, rel=1e-6)
 
@@ -125,8 +185,25 @@ def test_config_radius_is_the_rule_on_the_calibration_draw():
     p, q = corpus.make_corpus(cfg["profile"], cfg["n"], cfg["draw_queries"],
                               cfg["distribution_seed"],
                               cfg["distribution_seed"])
-    assert radius.select_radius(p, q[:256])["radius"] == pytest.approx(
-        cfg["radius"], rel=1e-6)
+    assert radius.select_radius(p, q[:256], cfg["profile"]["metric"])[
+        "radius"] == pytest.approx(cfg["radius"], rel=1e-6)
+
+
+@pytest.mark.parametrize("metric", ["l2", "ip"])
+def test_radius_leaves_some_queries_empty_and_some_not(metric):
+    prof = dict(_cfg()["profile"], metric=metric)
+    p, q = corpus.make_corpus(prof, 2000, 256, 0, 1)
+    sel = radius.select_radius(p, q, metric)
+    assert (sel["radius"] < 0) == (metric == "ip")
+    counts = np.array([len(t) for t in reference.exact_range(
+        p, q, sel["radius"], metric)])
+    assert 0 < (counts > 0).sum() < len(q)
+    assert counts.max() == sel["max_matches"]
+    assert (counts == 0).mean() == pytest.approx(sel["zero_frac"])
+    # the grid is geometric for l2, linear over -q.x for ip
+    g = radius.default_grid(p, q, metric).astype(np.float64)
+    steps = g[1:] / g[:-1] if metric == "l2" else np.diff(g)
+    assert np.allclose(steps, steps[0], rtol=1e-3)
 
 
 def _run(args, env_extra=None):
